@@ -51,11 +51,13 @@ def run() -> None:
         emit(f"kernel/moe_gemm_grouped_{impl}", us,
              f"E{Eg}xC{Cg}xd{dg}xF{Fg} max_abs_diff={diff:.2e}")
 
-    # VMEM working set of the production BlockSpec (bc=128, bf=512, d=4096)
-    bc, bf, dd = 128, 512, 4096
-    vmem = (bc * dd * 2 + 2 * dd * bf * 2 + bf * dd * 2 + bc * dd * 4)
-    emit("kernel/moe_gemm_vmem_bytes", 0.0,
-         f"{vmem / 2**20:.1f}MiB_of_~128MiB_v5e_VMEM_OK={vmem < 100 * 2**20}")
+    # VMEM working set of the blocks moe_ffn chooses for an 8-row
+    # decode batch at Mixtral width (d=4096, F=14336)
+    for dt in (jnp.bfloat16, jnp.float32):
+        bc, bf = ops.moe_ffn_blocks(8, 4096, 14336, dt)
+        vmem = ops.moe_ffn_vmem_bytes(bc, bf, 4096, dt)
+        emit(f"kernel/moe_gemm_vmem_bytes_{jnp.dtype(dt).name}", 0.0,
+             f"block_c={bc} block_f={bf} {vmem / 2**20:.2f}MiB")
 
     B, S, H, hd = 2, 1024, 8, 128
     q = jnp.asarray(rng.normal(size=(B, S, H, hd)), jnp.float32)

@@ -6,7 +6,7 @@ from repro.core.learned import (LearnedModel, evaluate_recall,
                                 train_from_trace)
 from repro.core.memory_tiers import (SwapQueue, TieredMemoryManager,
                                      plan_hbm_split)
-from repro.core.offload_engine import OffloadEngine
+from repro.core.offload_engine import OffloadEngine, init_offloaded_params
 from repro.core.paged_kv import PagedKVCache
 from repro.core.prefetch import (LearnedPredictor, MarkovPredictor,
                                  SpeculativePrefetcher)
@@ -19,6 +19,6 @@ __all__ = [
     "LearnedPredictor", "OffloadEngine", "MarkovPredictor",
     "PagedKVCache", "SpeculativePrefetcher", "StepTrace", "SwapQueue",
     "TierEvent", "TieredMemoryManager", "TraceRecorder", "Transfer",
-    "TransferEngine", "evaluate_recall", "train_from_trace",
-    "plan_hbm_split",
+    "TransferEngine", "evaluate_recall", "init_offloaded_params",
+    "plan_hbm_split", "train_from_trace",
 ]

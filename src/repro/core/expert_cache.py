@@ -2,9 +2,9 @@
 
 TPU-friendly layout: one stacked device buffer per weight matrix
 (``[n_slots, d, ff]`` etc., static shapes), a host-side slot map, and
-in-place slot updates (``buf.at[slot].set(w)``) standing in for the
-host→HBM DMA. All decisions (hit/miss/evict) happen on the host —
-control plane — exactly like the GPU baseline.
+in-place slot updates (``buf.at[slot].set(w)`` on a donated buffer)
+standing in for the host→HBM DMA. All decisions (hit/miss/evict)
+happen on the host — control plane — exactly like the GPU baseline.
 
 With a ``TieredMemoryManager`` attached (``tiers``), every install
 reports which memory tier the expert's master copy was served from
@@ -15,14 +15,26 @@ the pre-tiering single-host-tier cache.
 """
 from __future__ import annotations
 
+import functools
 from typing import Dict, List, Optional, Sequence, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.core.cache_policies import CachePolicy
 from repro.core.expert_store import ExpertStore
 from repro.core.faults import FetchOutcome
+
+
+@functools.partial(jax.jit, donate_argnums=0)
+def _set_slot(buf, slot, w):
+    """Write one expert matrix into slot ``slot`` of ``buf`` in place.
+    Donating ``buf`` matters at full width: a copying update allocates
+    a whole new [n_slots, d, ff] buffer per install, and with dispatch
+    running ahead of the device a layer's installs then hold several
+    such buffers at once."""
+    return buf.at[slot].set(w.astype(buf.dtype))
 
 
 class ExpertCache:
@@ -35,7 +47,7 @@ class ExpertCache:
     policy : eviction policy (see ``repro.core.cache_policies``).
     store : host-tier master copies the misses stream from.
     shapes : per-weight-matrix shapes, e.g. ``{"w1": (d, ff), ...}``.
-    dtype : device buffer dtype (fp32 on this backend).
+        The slot buffers take the store's dtype (``ExpertStore.dtype``).
     tiers : optional ``TieredMemoryManager`` — see module docstring.
 
     Counters (cumulative): ``hits``/``misses`` demand accesses,
@@ -48,7 +60,7 @@ class ExpertCache:
 
     def __init__(self, layer: int, n_slots: int, policy: CachePolicy,
                  store: ExpertStore, shapes: Dict[str, tuple],
-                 dtype=jnp.float32, tiers=None, faults=None):
+                 tiers=None, faults=None):
         assert policy.capacity == n_slots
         self.layer = layer
         self.n_slots = n_slots
@@ -56,7 +68,8 @@ class ExpertCache:
         self.store = store
         self.tiers = tiers
         self.faults = faults  # Optional[FaultInjector], shared stack-wide
-        self.buffers = {k: jnp.zeros((n_slots, *s), dtype) for k, s in shapes.items()}
+        self.buffers = {k: jnp.zeros((n_slots, *s), store.dtype)
+                        for k, s in shapes.items()}
         self.slot_of: Dict[int, int] = {}
         self._free: List[int] = list(range(n_slots))
         # counters
@@ -136,8 +149,7 @@ class ExpertCache:
                 self.corrupt_refetches += 1
                 w = self.store.fetch(key)
         for k, v in w.items():
-            self.buffers[k] = self.buffers[k].at[slot].set(
-                jnp.asarray(v, self.buffers[k].dtype))
+            self.buffers[k] = _set_slot(self.buffers[k], slot, jnp.asarray(v))
         self.slot_of[eid] = slot
         self.policy.on_insert(eid)
         self.bytes_transferred += self.store.expert_nbytes((self.layer, eid))

@@ -1,15 +1,17 @@
 """Host-tier expert parameter store.
 
 Experts live here (host RAM, numpy) by default — the "offloaded" tier.
-Supports bf16/fp32 storage and int8 per-channel quantization (the
-TPU-native stand-in for the paper's 2-bit HQQ GPU kernels; see
-DESIGN.md §hardware-adaptation). Byte accounting is real (``nbytes`` of
-what is actually stored).
+Weights keep the dtype they are given (bf16 or fp32), or are stored as
+int8 per-channel quantization (the TPU-native stand-in for the paper's
+2-bit HQQ GPU kernels; see DESIGN.md §hardware-adaptation). ``dtype`` is
+the given dtype either way: the device slots the experts stream into
+take it. Byte accounting is real (``nbytes`` of what is actually
+stored).
 """
 from __future__ import annotations
 
 import zlib
-from typing import Dict, Tuple
+from typing import Dict, Optional, Tuple
 
 import numpy as np
 
@@ -39,16 +41,25 @@ class ExpertStore:
         if quant not in ("none", "int8"):
             raise ValueError(f"quant must be 'none' or 'int8', got {quant!r}")
         self.quant = quant
+        self.dtype: Optional[np.dtype] = None  # of the weights given to put
         self._data: Dict[Key, dict] = {}
         self._checksums: Dict[Key, int] = {}  # lazy, of the fp32 payload
 
     def put(self, key: Key, weights: dict) -> None:
-        """weights: {'w1': [d,ff], 'w3': [d,ff], 'w2': [ff,d]} (device or np)."""
-        host = {k: np.asarray(v, dtype=np.float32) for k, v in weights.items()}
+        """weights: {'w1': [d,ff], 'w3': [d,ff], 'w2': [ff,d]} (device or
+        np), all of one dtype, the same for every expert."""
+        host = {k: np.asarray(v) for k, v in weights.items()}
+        dtypes = {v.dtype for v in host.values()}
+        if self.dtype is not None:
+            dtypes.add(self.dtype)
+        if len(dtypes) != 1:
+            raise ValueError(
+                f"expert weights mix dtypes {sorted(map(str, dtypes))}")
+        self.dtype = dtypes.pop()
         if self.quant == "int8":
             entry = {}
             for k, v in host.items():
-                q, s = _quantize_int8(v)
+                q, s = _quantize_int8(v.astype(np.float32))
                 entry[k] = ("int8", q, s)
             self._data[key] = entry
         else:
@@ -56,7 +67,7 @@ class ExpertStore:
         self._checksums.pop(key, None)
 
     def fetch(self, key: Key) -> dict:
-        """Dequantized fp32 weights (host)."""
+        """Host weights: as stored, or dequantized to fp32 (int8)."""
         entry = self._data[key]
         out = {}
         for k, (kind, v, s) in entry.items():
@@ -102,9 +113,9 @@ class ExpertStore:
         experts = params["layers"]["moe"]["experts"]
         L = experts["w1"].shape[0]
         E = experts["w1"].shape[1]
-        w1 = np.asarray(experts["w1"], np.float32)
-        w2 = np.asarray(experts["w2"], np.float32)
-        w3 = np.asarray(experts["w3"], np.float32)
+        w1 = np.asarray(experts["w1"])
+        w2 = np.asarray(experts["w2"])
+        w3 = np.asarray(experts["w3"])
         for l in range(L):
             for e in range(E):
                 store.put((l, e), {"w1": w1[l, e], "w3": w3[l, e], "w2": w2[l, e]})

@@ -14,6 +14,7 @@ expert GEMMs, slot updates).
 from __future__ import annotations
 
 import functools
+import math
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -36,6 +37,49 @@ from repro.models.layers import rms_norm, sinusoidal_positions
 
 def _layer_slice(tree, i):
     return jax.tree.map(lambda x: x[i], tree)
+
+
+def _without_experts(params):
+    """The param tree minus the stacked routed experts (they live in an
+    ``ExpertStore``, never on the device with the rest)."""
+    moe = {k: v for k, v in params["layers"]["moe"].items()
+           if k != "experts"}
+    return {**params, "layers": {**params["layers"], "moe": moe}}
+
+
+def init_offloaded_params(cfg, key, *, quant: str = "none"):
+    """Seeded MoE model built for offloading: ``(params, store)``.
+
+    ``params`` is ``tf.init_params(cfg, key)`` without the routed
+    experts (jitted, so the dropped expert init is dead code the
+    compiler removes); each ``(layer, expert)`` is drawn on the device
+    alone, from ``key`` folded with its layer and expert ids (same
+    distribution as ``init_moe``'s stacked experts), and moved
+    straight into the host ``store``. No more than one expert is ever
+    on the device, so full-width models whose experts exceed device
+    memory can be built. Pass both to ``OffloadEngine(params, cfg,
+    store=store, ...)``."""
+    from repro.models.layers import dense_init
+    params = jax.jit(lambda k: _without_experts(tf.init_params(cfg, k)))(key)
+    d, ff = cfg.d_model, cfg.expert_d_ff
+    dtype = jnp.dtype(cfg.dtype)
+    res_scale = 1.0 / math.sqrt(2 * cfg.num_layers)
+
+    @jax.jit
+    def one_expert(k):
+        k1, k3, k2 = jax.random.split(k, 3)
+        return {"w1": dense_init(k1, (d, ff), d, dtype=dtype),
+                "w3": dense_init(k3, (d, ff), d, dtype=dtype),
+                "w2": dense_init(k2, (ff, d), ff, scale=res_scale,
+                                 dtype=dtype)}
+
+    k_exp = jax.random.fold_in(key, 2**31 - 1)  # apart from init_params'
+    store = ExpertStore(quant=quant)
+    for l in range(cfg.num_layers):
+        k_l = jax.random.fold_in(k_exp, l)
+        for e in range(cfg.num_experts):
+            store.put((l, e), one_expert(jax.random.fold_in(k_l, e)))
+    return params, store
 
 
 @functools.partial(jax.jit, static_argnames=("impl",))
@@ -111,7 +155,12 @@ class OffloadEngine:
                  trace: Optional[TraceRecorder] = None,
                  tiers=None,   # repro.core.memory_tiers.TieredMemoryManager
                  faults=None,  # FaultPlan | FaultInjector | None
+                 store: Optional[ExpertStore] = None,
                  seed: int = 0):
+        """``params`` either holds the stacked experts, which move to a
+        new host ``ExpertStore``, or comes with the ``store`` that
+        already holds them (``init_offloaded_params``). Either way the
+        device keeps only the non-expert parameters."""
         assert cfg.is_moe, "offloading targets MoE experts"
         if prefetch not in (None, "spec", "markov", "learned"):
             raise ValueError(
@@ -121,7 +170,9 @@ class OffloadEngine:
             raise ValueError(
                 f"unknown ffn_impl={ffn_impl!r}: expected one of "
                 f"'xla', 'ref', 'pallas', 'pallas_interpret'")
-        self.params = params
+        if store is not None and store.quant != quant:
+            raise ValueError(f"quant={quant!r} but the given store holds "
+                             f"quant={store.quant!r} experts")
         self.cfg = cfg
         if isinstance(cache_slots, int):
             if cache_slots < 1:
@@ -143,7 +194,10 @@ class OffloadEngine:
         # arbiter, so fault-event indices are globally consistent;
         # None (the default) keeps every path bit-identical to pre-fault
         self.faults = as_injector(faults, trace=self.trace)
-        self.store = ExpertStore.from_params(params, cfg, quant=quant)
+        self.store = (store if store is not None
+                      else ExpertStore.from_params(params, cfg, quant=quant))
+        self.params = _without_experts(params)
+        self.dtype = self.params["embed"].dtype
 
         d, ff = cfg.d_model, cfg.expert_d_ff
         shapes = {"w1": (d, ff), "w3": (d, ff), "w2": (ff, d)}
@@ -212,7 +266,7 @@ class OffloadEngine:
     # ------------------------------------------------------------------
     def init_state(self, batch: int, cache_len: int):
         state = tf.init_decode_state(self.params, self.cfg, batch, cache_len,
-                                     dtype=jnp.float32)
+                                     dtype=self.dtype)
         # unstack attention caches into a python list for per-layer updates
         layers = [
             _layer_slice(state["layers"], l) for l in range(self.cfg.num_layers)

@@ -9,10 +9,13 @@ offloading streams weights for). TPU-native tiling:
   grid = (E, C/bc, F/bf), f innermost so the second GEMM accumulates
   into the fp32 output block across f-steps (classic K-loop pattern).
 
-VMEM working set per step (bf16 in, fp32 accum):
-  x (bc×d) + w1,w3 (d×bf each) + w2 (bf×d) + acc (bc×d fp32)
-  = e.g. bc=128, d=4096, bf=512: 1+4+4+4+2 ≈ 15 MiB — fits v5e VMEM.
-All matmul dims are kept multiples of 128 for the MXU by padding in
+VMEM working set per step: x (bc×d) + w1,w3 (d×bf each) + w2 (bf×d)
+in the input dtype + the fp32 output block (bc×d), all double-buffered
+by the pipeline. It must fit the compiler's scoped VMEM limit (16 MiB
+on v5e), not the chip's whole VMEM: at d=4096 and bc=8 that allows
+bf=256 in bf16 (~12.4 MiB) and bf=128 in fp32 (~12.5 MiB), and
+refuses bf=512 in either. ``ops.moe_ffn_blocks`` picks the blocks; all
+matmul dims are kept multiples of 128 for the MXU by padding in
 ``ops.moe_ffn``.
 """
 from __future__ import annotations

@@ -53,6 +53,36 @@ def _aligned_block(n: int, cap: int, mult: int) -> int:
     return mult
 
 
+# Default scoped-VMEM limit of a TPU kernel (16 MiB on v4/v5e; the
+# chips hold more VMEM, but the compiler refuses a kernel whose blocks
+# exceed the scoped limit), less headroom for the kernel's own
+# intermediates.
+_VMEM_BLOCK_BUDGET = 14 * 2**20
+
+
+def moe_ffn_vmem_bytes(block_c: int, block_f: int, d: int, dtype) -> int:
+    """VMEM the grouped-FFN kernel's blocks take: x [bc, d] and the
+    w1/w3 [d, bf] and w2 [bf, d] tiles in ``dtype``, plus the fp32
+    output block [bc, d], each double-buffered by the pipeline."""
+    s = jnp.dtype(dtype).itemsize
+    return 2 * (block_c * d + 3 * d * block_f) * s + 2 * block_c * d * 4
+
+
+def moe_ffn_blocks(C: int, d: int, F: int, dtype):
+    """Auto-chosen ``(block_c, block_f)`` for ``moe_ffn``: TPU-tile
+    aligned (sublane multiple of 8, lane multiple of 128), and the
+    largest ``block_f <= 512`` whose working set fits the scoped VMEM
+    budget at the padded width ``d`` (at Mixtral's d=4096 that is 256 in
+    bf16 and 128 in fp32)."""
+    bc = _aligned_block(C, 128, 8)
+    d_p = d + (-d) % 128
+    for cap in (512, 384, 256):
+        bf = _aligned_block(F, cap, 128)
+        if moe_ffn_vmem_bytes(bc, bf, d_p, dtype) <= _VMEM_BLOCK_BUDGET:
+            return bc, bf
+    return bc, 128
+
+
 @functools.partial(jax.jit, static_argnames=("impl", "block_c", "block_f"))
 def moe_ffn(x_e, w1, w3, w2, *, impl: str = "xla",
             block_c: int = None, block_f: int = None):
@@ -61,11 +91,11 @@ def moe_ffn(x_e, w1, w3, w2, *, impl: str = "xla",
     The pallas path pads every GEMM extent and slices the result back,
     so ragged shapes (``C % block_c != 0``, ``F % block_f != 0``, odd
     ``d``) are exact — parity-tested vs xla/ref. With the default
-    ``block_c=block_f=None`` the blocks are auto-chosen TPU-tile
-    aligned (fp32 (8, 128) tiles: sublane dim a multiple of 8, lane
-    dim a multiple of 128, ``d`` padded to 128); explicitly passed
-    blocks are honored as-is (interpret-mode testing knob — real-TPU
-    lane alignment is then the caller's responsibility).
+    ``block_c=block_f=None`` the blocks come from ``moe_ffn_blocks``
+    (tile aligned, ``d`` padded to 128, working set within scoped
+    VMEM); explicitly passed blocks are honored as-is (interpret-mode
+    testing knob — real-TPU alignment and VMEM are then the caller's
+    responsibility).
     """
     if impl == "ref":
         return ref.moe_gemm_ref(x_e, w1, w3, w2)
@@ -74,8 +104,9 @@ def moe_ffn(x_e, w1, w3, w2, *, impl: str = "xla",
     interpret = impl == "pallas_interpret"
     E, C, d = x_e.shape
     F = w1.shape[-1]
-    bc = block_c if block_c is not None else _aligned_block(C, 128, 8)
-    bf = block_f if block_f is not None else _aligned_block(F, 512, 128)
+    auto_c, auto_f = moe_ffn_blocks(C, d, F, x_e.dtype)
+    bc = block_c if block_c is not None else auto_c
+    bf = block_f if block_f is not None else auto_f
     x_p, C0 = _pad_to(x_e, 1, bc)
     x_p, _ = _pad_to(x_p, 2, 128)           # MXU contraction dim
     w1_p, _ = _pad_to(_pad_to(w1, 1, 128)[0], 2, bf)

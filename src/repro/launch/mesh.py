@@ -17,7 +17,11 @@ import jax
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    # Auto axes: the sharding rules place arrays with
+    # with_sharding_constraint, which refuses the Explicit axes that
+    # jax.make_mesh makes by default in recent JAX
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def batch_axes(mesh) -> Tuple[str, ...]:

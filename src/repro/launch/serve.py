@@ -13,6 +13,7 @@ import dataclasses
 import jax
 
 from repro.configs import get_config, reduced
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import transformer as tf
 from repro.serving import OffloadServer, ServingEngine
 
@@ -32,6 +33,7 @@ def main():
     ap.add_argument("--d-model", type=int, default=256)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = reduced(get_config(args.arch), layers=args.layers,
                   d_model=args.d_model)

@@ -17,6 +17,7 @@ import dataclasses
 
 
 from repro.configs import get_config, reduced
+from repro.launch.compile_cache import enable_compile_cache
 from repro.data import lm_batches
 from repro.training import save_checkpoint, train
 from repro.training.optimizer import AdamWConfig
@@ -36,6 +37,7 @@ def main():
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--ckpt", default=None)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

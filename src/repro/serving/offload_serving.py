@@ -92,10 +92,10 @@ from repro.serving.scheduler import Scheduler, make_scheduler
 
 
 def _planned_expert_bytes(cfg) -> int:
-    """HBM bytes ONE expert-cache slot pins in one layer: the fp32
-    device buffers (w1/w3/w2). Independent of host-store quantization —
-    dequantization happens at install, the slot is always fp32."""
-    return 3 * cfg.d_model * cfg.expert_d_ff * 4
+    """HBM bytes ONE expert-cache slot pins in one layer: the device
+    buffers (w1/w3/w2) in the model's dtype. Independent of host-store
+    quantization — dequantization happens at install."""
+    return 3 * cfg.d_model * cfg.expert_d_ff * jnp.dtype(cfg.dtype).itemsize
 
 
 class AdmissionRejected(RuntimeError):
@@ -131,6 +131,7 @@ class ContinuousOffloadServer:
                  resume_from_host: bool = True,
                  tier_lanes: int = 2,
                  faults=None,  # FaultPlan | FaultInjector | None
+                 store=None,   # ExpertStore with the experts (OffloadEngine)
                  request_timeout_steps: Optional[int] = None,
                  max_queue: Optional[int] = None,
                  shed_wait_steps: Optional[int] = None):
@@ -219,7 +220,8 @@ class ContinuousOffloadServer:
             params, cfg, cache_slots=cache_slots, policy=policy,
             policy_kw=policy_kw, learned_model=learned_model,
             prefetch=prefetch, quant=quant, hw=hw, overlap=overlap,
-            ffn_impl=ffn_impl, trace=self.trace, faults=faults)
+            ffn_impl=ffn_impl, trace=self.trace, faults=faults,
+            store=store)
         self.faults = self.engine.faults  # normalized FaultInjector|None
         self.request_timeout_steps = request_timeout_steps
         self.max_queue = max_queue
@@ -235,7 +237,7 @@ class ContinuousOffloadServer:
             n = kv_num_blocks if kv_num_blocks is not None else \
                 -(-max_batch * cache_len // kv_block_size)
             self.paged = PagedKVCache(n, kv_block_size, cfg=cfg,
-                                      dtype=jnp.float32)
+                                      dtype=self.engine.dtype)
             self.state = self.paged.state
         else:
             self.state = self.engine.init_state(max_batch, cache_len)
@@ -329,7 +331,7 @@ class ContinuousOffloadServer:
                 "cannot resize KV with active requests"
             self.cache_len = max(self.cache_len, n)
             self.paged = PagedKVCache(need, self.kv_block_size,
-                                      cfg=self.cfg, dtype=jnp.float32)
+                                      cfg=self.cfg, dtype=self.engine.dtype)
             self.state = self.paged.state
             if self.tiers is not None:
                 self.tiers.set_hbm_plan(
@@ -342,6 +344,12 @@ class ContinuousOffloadServer:
         assert self.num_active == 0, "cannot resize KV with active requests"
         self.cache_len = n
         self.state = self.engine.init_state(self.max_batch, n)
+
+    @property
+    def last_logits(self):
+        """[rows, V] fp32 logits of the last step; with
+        ``prefill_chunk == 1`` row b is slot b."""
+        return self._logits
 
     @property
     def num_active(self) -> int:

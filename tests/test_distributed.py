@@ -32,7 +32,8 @@ SCRIPT = textwrap.dedent("""
     from repro.models import transformer as tf
     from repro.models.sharding import sharding_ctx, param_pspecs, sanitize_spec
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     rules = {"batch": ("data",), "model": "model", "heads": "model",
              "vocab": "model", "experts": "model", "capacity": "data",
              "shard_kv": True, "experts_mode": "ep", "_data_size": 2}

@@ -152,3 +152,62 @@ def test_store_from_params_roundtrip(mixtral_setup):
     w = store.fetch((1, 3))
     want = np.asarray(params["layers"]["moe"]["experts"]["w1"][1, 3])
     np.testing.assert_allclose(w["w1"], want, rtol=1e-6)
+
+
+def test_store_keeps_one_dtype():
+    store = ExpertStore()
+    bf = np.ones((2, 3), jnp.bfloat16)
+    store.put((0, 0), {"w1": bf, "w3": bf, "w2": bf.T})
+    assert store.dtype == jnp.bfloat16
+    assert store.fetch((0, 0))["w1"].dtype == jnp.bfloat16
+    with pytest.raises(ValueError, match="mix dtypes"):
+        store.put((0, 1), {"w1": bf.astype(np.float32), "w3": bf,
+                           "w2": bf.T})
+    with pytest.raises(ValueError, match="mix dtypes"):
+        store.put((0, 1), {k: np.ones((2, 3), np.float32)
+                           for k in ("w1", "w3", "w2")})
+
+
+def test_engine_keeps_experts_off_the_device(mixtral_setup):
+    cfg, params = mixtral_setup
+    eng = OffloadEngine(params, cfg, cache_slots=2, policy="lru")
+    assert "experts" not in eng.params["layers"]["moe"]
+    assert "router" in eng.params["layers"]["moe"]
+    assert sorted(eng.store.keys()) == [(l, e) for l in range(cfg.num_layers)
+                                        for e in range(cfg.num_experts)]
+    with pytest.raises(ValueError, match="quant"):
+        OffloadEngine(params, cfg, cache_slots=2, quant="int8",
+                      store=eng.store)
+
+
+def test_offloaded_init_serves_in_the_model_dtype():
+    """init_offloaded_params: the non-expert tree is init_params' own,
+    every expert is in the host store, and the device copies (slots,
+    paged KV pool) take the model's bf16."""
+    from repro.core import init_offloaded_params
+    from repro.serving import ContinuousOffloadServer
+    cfg = reduced(get_config("mixtral-8x7b"), layers=2, d_model=64,
+                  experts=4, vocab=128)
+    assert cfg.dtype == "bfloat16"
+    params, store = init_offloaded_params(cfg, jax.random.PRNGKey(0))
+    want = tf.init_params(cfg, jax.random.PRNGKey(0))
+    assert "experts" not in params["layers"]["moe"]
+    del want["layers"]["moe"]["experts"]
+    for got_leaf, want_leaf in zip(jax.tree.leaves(params),
+                                   jax.tree.leaves(want)):
+        assert got_leaf.dtype == want_leaf.dtype
+        np.testing.assert_array_equal(np.asarray(got_leaf, np.float32),
+                                      np.asarray(want_leaf, np.float32))
+    assert store.dtype == jnp.bfloat16
+    assert len(store.keys()) == cfg.num_layers * cfg.num_experts
+    w = store.fetch((1, 2))
+    assert w["w1"].shape == (cfg.d_model, cfg.expert_d_ff)
+    assert w["w2"].shape == (cfg.expert_d_ff, cfg.d_model)
+
+    srv = ContinuousOffloadServer(params, cfg, store=store, cache_slots=2,
+                                  max_batch=2, cache_len=16)
+    assert srv.engine.caches[0].buffers["w1"].dtype == jnp.bfloat16
+    assert srv.state["layers"][0]["k"].dtype == jnp.bfloat16
+    rid = srv.submit([1, 2, 3], max_new=2)
+    assert len(srv.run()[rid]) == 5
+    assert np.isfinite(np.asarray(srv.last_logits)).all()
