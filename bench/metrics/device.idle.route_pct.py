@@ -1,0 +1,11 @@
+"""Share of the traced window in which the chip runs nothing while the
+innermost program span on the host is ``engine.route`` (the router's
+matmul, its read back to the host and the top-k;
+``span_reduce.idle_ns_by_span``)."""
+import span_reduce
+
+SPAN = "engine.route"
+
+
+def read(ctx):
+    return span_reduce.idle_pct(ctx.profile, lambda name: name == SPAN)

@@ -1,0 +1,12 @@
+"""Share of the traced window in which the chip runs nothing while the
+innermost program span on the host is ``server.step``: the step's own
+code outside its other spans (the token upload, advancing and retiring
+requests). One of the six parts of ``device.idle.step_other_pct``
+(``span_reduce.idle_ns_by_span``)."""
+import span_reduce
+
+SPAN = "server.step"
+
+
+def read(ctx):
+    return span_reduce.idle_pct(ctx.profile, lambda name: name == SPAN)

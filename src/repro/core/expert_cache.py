@@ -124,35 +124,39 @@ class ExpertCache:
         with corrupt deliveries exercises the REAL checksum path: the
         payload is actually corrupted, the mismatch detected, and the
         fetch redelivered."""
-        evicted = None
-        if self._free:
-            slot = self._free.pop()
-        else:
-            victim = self.policy.choose_victim(pinned)
-            slot = self.slot_of.pop(victim)
-            self.policy.remove(victim)
-            evicted = victim
+        nbytes = self.store.expert_nbytes((self.layer, eid))
+        with jax.profiler.TraceAnnotation(
+                "expert_cache.install", layer=self.layer, expert=int(eid),
+                bytes=nbytes, demand=int(demand)):
+            evicted = None
+            if self._free:
+                slot = self._free.pop()
+            else:
+                victim = self.policy.choose_victim(pinned)
+                slot = self.slot_of.pop(victim)
+                self.policy.remove(victim)
+                evicted = victim
+                if self.tiers is not None:
+                    self.tiers.expert_evicted((self.layer, victim))
+            tier = "host"
             if self.tiers is not None:
-                self.tiers.expert_evicted((self.layer, victim))
-        tier = "host"
-        if self.tiers is not None:
-            tier = self.tiers.fetch_expert((self.layer, eid), demand=demand)
-        w = self.store.fetch((self.layer, eid))
-        if outcome is not None and outcome.corrupt_deliveries and \
-                self.faults is not None:
-            key = (self.layer, eid)
-            for _ in range(outcome.corrupt_deliveries):
-                bad = self.faults.corrupt_payload(w)
-                if self.store.verify(key, bad):
-                    w = bad  # crc collision: corruption slips through
-                    continue
-                self.corrupt_refetches += 1
-                w = self.store.fetch(key)
-        for k, v in w.items():
-            self.buffers[k] = _set_slot(self.buffers[k], slot, jnp.asarray(v))
-        self.slot_of[eid] = slot
-        self.policy.on_insert(eid)
-        self.bytes_transferred += self.store.expert_nbytes((self.layer, eid))
+                tier = self.tiers.fetch_expert((self.layer, eid), demand=demand)
+            w = self.store.fetch((self.layer, eid))
+            if outcome is not None and outcome.corrupt_deliveries and \
+                    self.faults is not None:
+                key = (self.layer, eid)
+                for _ in range(outcome.corrupt_deliveries):
+                    bad = self.faults.corrupt_payload(w)
+                    if self.store.verify(key, bad):
+                        w = bad  # crc collision: corruption slips through
+                        continue
+                    self.corrupt_refetches += 1
+                    w = self.store.fetch(key)
+            for k, v in w.items():
+                self.buffers[k] = _set_slot(self.buffers[k], slot, jnp.asarray(v))
+            self.slot_of[eid] = slot
+            self.policy.on_insert(eid)
+        self.bytes_transferred += nbytes
         return slot, evicted, tier
 
     def access(self, eids: Sequence[int],

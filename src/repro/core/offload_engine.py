@@ -356,7 +356,8 @@ class OffloadEngine:
         """
         cfg = self.cfg
         x = rms_norm(h, p_l["ln2"], cfg.norm_eps)
-        ids, probs = self._route(p_l, x)   # [B,k]
+        with jax.profiler.TraceAnnotation("engine.route", layer=layer):
+            ids, probs = self._route(p_l, x)   # [B,k]
         B = ids.shape[0]
 
         # union of needed experts over ACTIVE rows, most-weighted first
@@ -402,13 +403,15 @@ class OffloadEngine:
                     else chunk)
             if not comp:
                 continue
-            w = cache.gather(comp)
-            comb = _combine_matrix(comp, ids, probs, active,
-                                   cfg.num_experts)
-            if scale is not None:
-                comb = (comb * scale[:, None]).astype(np.float32)
-            y = y + _grouped_ffn(x[:, 0, :], w["w1"], w["w3"], w["w2"],
-                                 jnp.asarray(comb), impl=self.ffn_impl)
+            with jax.profiler.TraceAnnotation(
+                    "engine.ffn", layer=layer, experts=len(comp), rows=B):
+                w = cache.gather(comp)
+                comb = _combine_matrix(comp, ids, probs, active,
+                                       cfg.num_experts)
+                if scale is not None:
+                    comb = (comb * scale[:, None]).astype(np.float32)
+                y = y + _grouped_ffn(x[:, 0, :], w["w1"], w["w3"], w["w2"],
+                                     jnp.asarray(comb), impl=self.ffn_impl)
         h = h + y[:, None, :].astype(h.dtype)
 
         # --- simulated pipeline clock for this layer ------------------
@@ -521,6 +524,11 @@ class OffloadEngine:
         with the dense one, so everything downstream (routing, caches,
         trace, clock) is unchanged.
         Returns (logits [B,V], state).
+
+        The call, and each layer's attention, MoE, routing, expert
+        installs and FFN chunks, and the logits, are host spans
+        (``engine.*``, ``expert_cache.install``) that a JAX profiler
+        trace records (docs/traces.md, "Host spans").
         """
         cfg = self.cfg
         params = self.params
@@ -533,127 +541,133 @@ class OffloadEngine:
             active = [True] * B
         n_active = sum(1 for a in active if a)
         assert n_active >= 1, "decode step with no active rows"
-        pos_vec = jnp.asarray(list(positions), jnp.int32)
+        with jax.profiler.TraceAnnotation("engine.decode", rows=n_active):
+            pos_vec = jnp.asarray(list(positions), jnp.int32)
 
-        h = params["embed"][tokens]
-        if cfg.pos_emb == "sinusoidal":
-            h = h + sinusoidal_positions(pos_vec[:, None],
-                                         cfg.d_model).astype(h.dtype)
+            h = params["embed"][tokens]
+            if cfg.pos_emb == "sinusoidal":
+                h = h + sinusoidal_positions(pos_vec[:, None],
+                                             cfg.d_model).astype(h.dtype)
 
-        # guesses issued at layer l are consumed at layer l+1 of the SAME
-        # token pass (the prefetch travels ahead of the compute wavefront);
-        # each entry is (guess, moved, fault outcomes of the moved ids)
-        pending: Dict[int, Tuple[Tuple[int, ...], Tuple[int, ...], Dict]] = {}
-        step_misses = 0
-        step_prefetch = 0
-        act_rows = np.asarray([b for b in range(B) if active[b]], np.int32)
-        # the executed pipeline clock starts where the last step ended;
-        # per-layer stages advance it by compute + exposed stall
-        self._clock = self.sim_time
-        self._step_fault_stall_s = 0.0
-        step_degraded = [False] * n_active
-        if self.faults is not None:
-            self.faults.now = self.sim_time
+            # guesses issued at layer l are consumed at layer l+1 of the SAME
+            # token pass (the prefetch travels ahead of the compute wavefront);
+            # each entry is (guess, moved, fault outcomes of the moved ids)
+            pending: Dict[int, Tuple[Tuple[int, ...], Tuple[int, ...], Dict]] = {}
+            step_misses = 0
+            step_prefetch = 0
+            act_rows = np.asarray([b for b in range(B) if active[b]], np.int32)
+            # the executed pipeline clock starts where the last step ended;
+            # per-layer stages advance it by compute + exposed stall
+            self._clock = self.sim_time
+            self._step_fault_stall_s = 0.0
+            step_degraded = [False] * n_active
+            if self.faults is not None:
+                self.faults.now = self.sim_time
 
-        for l in range(cfg.num_layers):
-            p_l = _layer_slice(params["layers"], l)
-            if block_tables is None:
-                h, state["layers"][l] = tf._attn_decode_multipos(
-                    p_l, cfg, h, state["layers"][l], pos_vec)
-            else:
-                h, state["layers"][l] = tf._attn_decode_paged(
-                    p_l, cfg, h, state["layers"][l], pos_vec, block_tables)
+            for l in range(cfg.num_layers):
+                p_l = _layer_slice(params["layers"], l)
+                with jax.profiler.TraceAnnotation("engine.attention", layer=l):
+                    if block_tables is None:
+                        h, state["layers"][l] = tf._attn_decode_multipos(
+                            p_l, cfg, h, state["layers"][l], pos_vec)
+                    else:
+                        h, state["layers"][l] = tf._attn_decode_paged(
+                            p_l, cfg, h, state["layers"][l], pos_vec,
+                            block_tables)
 
-            # --- speculative guess for layer l+1 (paper §3.2) ---------
-            if self.spec is not None and l + 1 < cfg.num_layers:
-                p_next = _layer_slice(params["layers"], l + 1)
-                guess = self.spec.guess(h[act_rows], p_next["ln2"],
-                                        p_next["moe"]["router"])
-                moved = self.caches[l + 1].prefetch(guess)
-                step_prefetch += len(moved)
-                pending[l + 1] = (guess, tuple(moved),
-                                  dict(self.caches[l + 1]
-                                       .last_prefetch_outcomes))
-                if self.overlap:
-                    # issued before layer l's MoE computes: the copy
-                    # has layer l's compute window to hide under
-                    self._issue_transfers(
-                        l + 1, moved, demand=False,
-                        outcomes=self.caches[l + 1].last_prefetch_outcomes
-                        or None)
-
-            pg, pm, po = pending.get(l, ((), (), {}))
-            h, acts, misses, req_deg = self._moe_offloaded(
-                p_l, l, h, pg, pm, po, prompt_ids, token_indices, active)
-            step_misses += misses
-            for i, d in enumerate(req_deg):
-                step_degraded[i] |= d
-            predictor = self.markov if self.markov is not None else self.learned
-            if predictor is not None:
-                if self.learned is not None:
-                    # keep the learned feature walk aligned with training
-                    self.learned.observe(l, acts)
-                if l > 0:
-                    predictor.update(l - 1, self._prev_acts.get(l - 1, ()),
-                                     acts)
-                if l + 1 < cfg.num_layers:
-                    # predict l+1 from THIS token's layer-l set — the
-                    # same-token l -> l+1 transition the table is
-                    # trained on. (Guessing from self._prev_acts[l]
-                    # here fed predict the PREVIOUS token's layer-l
-                    # set: train/predict skew that wasted the learned
-                    # transitions whenever consecutive tokens routed
-                    # differently — regression-tested.)
-                    guess = predictor.predict(l, acts)
+                # --- speculative guess for layer l+1 (paper §3.2) ---------
+                if self.spec is not None and l + 1 < cfg.num_layers:
+                    p_next = _layer_slice(params["layers"], l + 1)
+                    guess = self.spec.guess(h[act_rows], p_next["ln2"],
+                                            p_next["moe"]["router"])
                     moved = self.caches[l + 1].prefetch(guess)
                     step_prefetch += len(moved)
                     pending[l + 1] = (guess, tuple(moved),
                                       dict(self.caches[l + 1]
                                            .last_prefetch_outcomes))
                     if self.overlap:
-                        # predicted AFTER layer l's MoE (the clock has
-                        # advanced past it): the copy hides under layer
-                        # l+1's attention + FFN compute
+                        # issued before layer l's MoE computes: the copy
+                        # has layer l's compute window to hide under
                         self._issue_transfers(
                             l + 1, moved, demand=False,
-                            outcomes=self.caches[l + 1]
-                            .last_prefetch_outcomes or None)
-            self._prev_acts[l] = acts
+                            outcomes=self.caches[l + 1].last_prefetch_outcomes
+                            or None)
 
-        logits = tf.logits_from_hidden(params, cfg, h)[:, 0]
+                pg, pm, po = pending.get(l, ((), (), {}))
+                with jax.profiler.TraceAnnotation("engine.moe", layer=l):
+                    h, acts, misses, req_deg = self._moe_offloaded(
+                        p_l, l, h, pg, pm, po, prompt_ids, token_indices,
+                        active)
+                step_misses += misses
+                for i, d in enumerate(req_deg):
+                    step_degraded[i] |= d
+                predictor = self.markov if self.markov is not None else self.learned
+                if predictor is not None:
+                    if self.learned is not None:
+                        # keep the learned feature walk aligned with training
+                        self.learned.observe(l, acts)
+                    if l > 0:
+                        predictor.update(l - 1, self._prev_acts.get(l - 1, ()),
+                                         acts)
+                    if l + 1 < cfg.num_layers:
+                        # predict l+1 from THIS token's layer-l set — the
+                        # same-token l -> l+1 transition the table is
+                        # trained on. (Guessing from self._prev_acts[l]
+                        # here fed predict the PREVIOUS token's layer-l
+                        # set: train/predict skew that wasted the learned
+                        # transitions whenever consecutive tokens routed
+                        # differently — regression-tested.)
+                        guess = predictor.predict(l, acts)
+                        moved = self.caches[l + 1].prefetch(guess)
+                        step_prefetch += len(moved)
+                        pending[l + 1] = (guess, tuple(moved),
+                                          dict(self.caches[l + 1]
+                                               .last_prefetch_outcomes))
+                        if self.overlap:
+                            # predicted AFTER layer l's MoE (the clock has
+                            # advanced past it): the copy hides under layer
+                            # l+1's attention + FFN compute
+                            self._issue_transfers(
+                                l + 1, moved, demand=False,
+                                outcomes=self.caches[l + 1]
+                                .last_prefetch_outcomes or None)
+                self._prev_acts[l] = acts
 
-        # simulated clock: one step serves n_active tokens; misses are
-        # already batch-union counts (amortization is emergent)
-        if self.overlap:
-            # executed pipeline: per-layer stages already advanced the
-            # clock by compute + exposed stall; transfers that finished
-            # under compute cost nothing (the analytic step_latency
-            # formula is only the synchronous upper bound — validated
-            # against this timeline in tests and bench_overlap)
-            self.sim_time = self._clock
-            self.xfer.advance(self.sim_time)
-        else:
-            self.sim_time += self.cost.step_latency(
-                step_misses / cfg.num_layers,
-                prefetch_per_layer=step_prefetch / cfg.num_layers,
-                batch=n_active)
-            if self._step_fault_stall_s:
-                # retries/backoff/abandoned chains land ON TOP of the
-                # analytic formula (which prices one transfer per miss)
-                self.sim_time += self._step_fault_stall_s
-        if self.faults is not None:
-            self.faults.now = self.sim_time
-            self.degraded_tokens += sum(1 for d in step_degraded if d)
-        if self.tiers is not None:
-            # tier stalls (disk-resident demand fetches, in-flight
-            # demotion waits) land on top of the host-link pricing
-            # above; then the arbiter's clock catches up so background
-            # swaps complete
-            self.sim_time += self.tiers.drain_stall()
-            self.tiers.advance(self.sim_time)
-        self.tokens_done += n_active
-        self._steps_done += 1
-        return logits, state
+            with jax.profiler.TraceAnnotation("engine.logits"):
+                logits = tf.logits_from_hidden(params, cfg, h)[:, 0]
+
+            # simulated clock: one step serves n_active tokens; misses are
+            # already batch-union counts (amortization is emergent)
+            if self.overlap:
+                # executed pipeline: per-layer stages already advanced the
+                # clock by compute + exposed stall; transfers that finished
+                # under compute cost nothing (the analytic step_latency
+                # formula is only the synchronous upper bound — validated
+                # against this timeline in tests and bench_overlap)
+                self.sim_time = self._clock
+                self.xfer.advance(self.sim_time)
+            else:
+                self.sim_time += self.cost.step_latency(
+                    step_misses / cfg.num_layers,
+                    prefetch_per_layer=step_prefetch / cfg.num_layers,
+                    batch=n_active)
+                if self._step_fault_stall_s:
+                    # retries/backoff/abandoned chains land ON TOP of the
+                    # analytic formula (which prices one transfer per miss)
+                    self.sim_time += self._step_fault_stall_s
+            if self.faults is not None:
+                self.faults.now = self.sim_time
+                self.degraded_tokens += sum(1 for d in step_degraded if d)
+            if self.tiers is not None:
+                # tier stalls (disk-resident demand fetches, in-flight
+                # demotion waits) land on top of the host-link pricing
+                # above; then the arbiter's clock catches up so background
+                # swaps complete
+                self.sim_time += self.tiers.drain_stall()
+                self.tiers.advance(self.sim_time)
+            self.tokens_done += n_active
+            self._steps_done += 1
+            return logits, state
 
     # ------------------------------------------------------------------
     def prefill_tokens(self, state, tokens, positions: Sequence[int], *,

@@ -78,6 +78,7 @@ from __future__ import annotations
 from collections import deque
 from typing import Deque, Dict, List, Optional, Sequence
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -575,26 +576,10 @@ class ContinuousOffloadServer:
                     left -= extra
         return chunks
 
-    def step(self) -> List[int]:
-        """One token-boundary: admit, plan chunk budgets, grow/steal KV
-        pages (paged), decode every active slot — ``chunks[rid]``
-        virtual rows at consecutive positions when catching up —
-        sample/advance, retire. Returns rids retired now (completed,
-        timed out, or shed — check ``Request.status``)."""
-        expired = self._expire_and_shed()
-        self._admit()
-        chunks = self._plan_chunks([r for r in self.slots if r is not None])
-        if self.paged is not None:
-            self._ensure_kv(chunks)
-            if self.tiers is not None:
-                # growth that claimed blocks whose park-demotion is
-                # still copying out must wait for those lanes to land
-                self.tiers.note_block_claims(self.paged.free_blocks,
-                                             self.engine.sim_time)
-        active = [r is not None for r in self.slots]
-        if not any(active):
-            return expired
-
+    def _layout(self, chunks: Dict[int, int]):
+        """The step's engine inputs: ``(tokens [rows, 1], positions,
+        prompt_ids, row_active, last_row, block_tables)``, where
+        ``last_row[rid]`` is the row request ``rid`` samples from."""
         B = self.max_batch
         last_row: Dict[int, int] = {}
         if self.prefill_chunk == 1:
@@ -604,7 +589,7 @@ class ContinuousOffloadServer:
             positions = [0] * B
             prompt_ids = [0] * B
             row_rids: List[Optional[int]] = [None] * B
-            row_active = active
+            row_active = [r is not None for r in self.slots]
             for b, req in enumerate(self.slots):
                 if req is None:
                     continue
@@ -643,39 +628,78 @@ class ContinuousOffloadServer:
         block_tables = None
         if self.paged is not None:
             block_tables = jnp.asarray(self.paged.table_array(row_rids))
+        return (tokens, positions, prompt_ids, row_active, last_row,
+                block_tables)
 
-        t0 = self.engine.sim_time
-        logits, self.state = self.engine.decode_tokens(
-            self.state, jnp.asarray(tokens), positions,
-            prompt_ids=prompt_ids, active=row_active,
-            block_tables=block_tables)
-        self._step_times.append(self.engine.sim_time - t0)
-        self._logits = logits
-        self.step_count += 1
+    def step(self) -> List[int]:
+        """One token-boundary: admit, plan chunk budgets, grow/steal KV
+        pages (paged), decode every active slot — ``chunks[rid]``
+        virtual rows at consecutive positions when catching up —
+        sample/advance, retire. Returns rids retired now (completed,
+        timed out, or shed — check ``Request.status``).
 
-        retired: List[int] = []
-        for b in range(B):
-            req = self.slots[b]
-            if req is None:
-                continue
-            n = chunks[req.rid]
-            req.pos += n
-            req.steps_advanced += 1
-            if req.tenant is not None:
-                self.tenant_service[req.tenant] = \
-                    self.tenant_service.get(req.tenant, 0) + n
-            if req.pos < len(req.tokens):
-                continue  # still streaming known tokens (prefill)
-            if req.eos_hit or len(req.out) >= req.max_new:
-                # every known token has been fed (matching generate(),
-                # which decodes the final sampled token too)
-                self._retire(req)
-                retired.append(req.rid)
-                continue
-            req.out.append(self._sample(req, logits[last_row[req.rid]]))
-            if self.eos_id is not None and req.out[-1] == self.eos_id:
-                req.eos_hit = True
-        return expired + retired
+        The step is a ``server.step`` host span (its number and active
+        rows) around ``server.schedule``, the engine's ``engine.decode``
+        and a ``server.sample`` per sampled request (docs/traces.md,
+        "Host spans")."""
+        with jax.profiler.TraceAnnotation("server.step",
+                                          step=self.step_count) as span:
+            with jax.profiler.TraceAnnotation("server.schedule"):
+                expired = self._expire_and_shed()
+                self._admit()
+                chunks = self._plan_chunks(
+                    [r for r in self.slots if r is not None])
+                if self.paged is not None:
+                    self._ensure_kv(chunks)
+                    if self.tiers is not None:
+                        # growth that claimed blocks whose park-demotion
+                        # is still copying out must wait for those lanes
+                        # to land
+                        self.tiers.note_block_claims(self.paged.free_blocks,
+                                                     self.engine.sim_time)
+                batch = self._layout(chunks) if self.num_active else None
+            if batch is None:
+                span.set_metadata(rows=0)
+                return expired
+            (tokens, positions, prompt_ids, row_active, last_row,
+             block_tables) = batch
+            span.set_metadata(rows=sum(row_active))
+
+            t0 = self.engine.sim_time
+            logits, self.state = self.engine.decode_tokens(
+                self.state, jnp.asarray(tokens), positions,
+                prompt_ids=prompt_ids, active=row_active,
+                block_tables=block_tables)
+            self._step_times.append(self.engine.sim_time - t0)
+            self._logits = logits
+            self.step_count += 1
+
+            retired: List[int] = []
+            for b in range(self.max_batch):
+                req = self.slots[b]
+                if req is None:
+                    continue
+                n = chunks[req.rid]
+                req.pos += n
+                req.steps_advanced += 1
+                if req.tenant is not None:
+                    self.tenant_service[req.tenant] = \
+                        self.tenant_service.get(req.tenant, 0) + n
+                if req.pos < len(req.tokens):
+                    continue  # still streaming known tokens (prefill)
+                if req.eos_hit or len(req.out) >= req.max_new:
+                    # every known token has been fed (matching generate(),
+                    # which decodes the final sampled token too)
+                    self._retire(req)
+                    retired.append(req.rid)
+                    continue
+                with jax.profiler.TraceAnnotation("server.sample",
+                                                  rid=req.rid):
+                    req.out.append(
+                        self._sample(req, logits[last_row[req.rid]]))
+                if self.eos_id is not None and req.out[-1] == self.eos_id:
+                    req.eos_hit = True
+            return expired + retired
 
     def _sample(self, req: Request, row) -> int:
         temp = self.temperature if req.temperature is None else req.temperature

@@ -2,6 +2,7 @@
 (Pallas kernels in interpret mode), and its refusal to run without a
 TPU."""
 import dataclasses
+import gc
 import importlib.util
 import os
 
@@ -23,6 +24,9 @@ def tiny_cfg():
 
 
 def test_all_phases_pass_at_tiny_size(tiny_cfg):
+    # the run checks the largest live device array: free what earlier
+    # tests in this process left in reference cycles first
+    gc.collect()
     lines = []
     res = cs.run(tiny_cfg, pallas_impl="pallas_interpret",
                  prompt_lens=(3, 5), max_new=3, report=lines.append)
