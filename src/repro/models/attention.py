@@ -305,35 +305,76 @@ def gqa_decode_multipos(p, cfg, x, cache, pos_vec):
     bf16 operands + fp32 accumulation; the cache is never up-cast (the
     per-step f32 convert dominated decode HBM traffic — EXPERIMENTS.md).
     """
-    B = x.shape[0]
-    L = cache["k"].shape[1]
-    positions = jnp.reshape(pos_vec, (B, 1)).astype(jnp.int32)
-    q, k_new, v_new = _project_qkv(p, cfg, x, positions, rope=True)
+    q, k_new, v_new = gqa_decode_qkv(p, cfg, x, pos_vec)
+    cache = gqa_append(cache, k_new, v_new, dense_cells(pos_vec))
+    out = gqa_attend(q, cache["k"], cache["v"], pos_vec)
+    return gqa_out(p["wo"], out, x.dtype), cache
 
-    # per-row scatter: row b's new K/V lands at slot pos_vec[b] (an
-    # in-place XLA scatter, not a full-cache select)
-    rows = jnp.arange(B)
-    k = cache["k"].at[rows, positions[:, 0]].set(
-        k_new[:, 0].astype(cache["k"].dtype))
-    v = cache["v"].at[rows, positions[:, 0]].set(
-        v_new[:, 0].astype(cache["v"].dtype))
 
-    H, KV, hd = q.shape[2], k.shape[2], cfg.head_dim
-    G = H // KV
-    qf = q.reshape(B, KV, G, hd).astype(k.dtype)
+# The pieces of one decode step, shared by the dense and paged layouts
+# (and jitted piecewise by ``repro.models.transformer``).
+def gqa_decode_qkv(p, cfg, x, pos_vec):
+    """x [B,1,d], pos_vec [B] -> q [B,Hp,hd] and the new k/v [B,KVp,hd],
+    roped at each row's position."""
+    positions = jnp.reshape(pos_vec, (x.shape[0], 1)).astype(jnp.int32)
+    q, k, v = _project_qkv(p, cfg, x, positions, rope=True)
+    return q[:, 0], k[:, 0], v[:, 0]
+
+
+def dense_cells(pos_vec):
+    """Dense-cache cell of each row's new K/V: (row, pos)."""
+    return jnp.arange(pos_vec.shape[0]), pos_vec.astype(jnp.int32)
+
+
+def paged_cells(block_tables, pos_vec, block_size: int):
+    """Pool cell of each row's new K/V: (table[pos // bs], pos % bs).
+    Tables of live requests never alias (allocator invariant), so rows
+    write disjoint cells."""
+    pos = pos_vec.astype(jnp.int32)
+    blk = block_tables[jnp.arange(pos.shape[0]), pos // block_size]
+    return blk, pos % block_size
+
+
+def gqa_append(cache, k_new, v_new, cells):
+    """Scatter row b's k/v [B,KV,hd] into ``cache`` at ``cells`` (an
+    in-place XLA scatter, not a full-cache select)."""
+    return {"k": cache["k"].at[cells].set(k_new.astype(cache["k"].dtype)),
+            "v": cache["v"].at[cells].set(v_new.astype(cache["v"].dtype))}
+
+
+def gqa_attend(q, k, v, pos_vec):
+    """q [B,H,hd] over each row's logical K/V strip k/v [B,L,KV,hd],
+    keys at index <= pos_vec[b] -> [B,H,hd] fp32. bf16 operands, fp32
+    accumulation; the strip is never up-cast."""
+    B, H, hd = q.shape
+    L, KV = k.shape[1], k.shape[2]
+    qf = q.reshape(B, KV, H // KV, hd).astype(k.dtype)
     s = jnp.einsum("bkgh,blkh->bkgl", qf, k,
                    preferred_element_type=jnp.float32)
     s = s / math.sqrt(hd)
-
-    valid = jnp.arange(L)[None, :] <= positions  # [B, L]
+    valid = jnp.arange(L)[None, :] <= pos_vec[:, None]  # [B, L]
     s = jnp.where(valid[:, None, None, :], s, NEG_INF)
     w = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bkgl,blkh->bkgh", w.astype(v.dtype), v,
                      preferred_element_type=jnp.float32)
-    out = out.reshape(B, 1, H, hd).astype(x.dtype)
-    wo = _pad_heads(p["wo"], H, 0)
-    y = jnp.einsum("bshk,hkd->bsd", out, wo)
-    return y, {"k": k, "v": v}
+    return out.reshape(B, H, hd)
+
+
+def gqa_attend_paged(q, cache, block_tables, pos_vec):
+    """``gqa_attend`` over the strip each row's table gathers from the
+    pool: [B,T,bs,kv,hd] -> [B,T*bs,kv,hd]."""
+    B, T = block_tables.shape
+    k, v = cache["k"], cache["v"]
+    kg = k[block_tables].reshape(B, T * k.shape[1], *k.shape[2:])
+    vg = v[block_tables].reshape(B, T * v.shape[1], *v.shape[2:])
+    return gqa_attend(q, kg, vg, pos_vec)
+
+
+def gqa_out(wo, out, dtype):
+    """Heads' output [B,H,hd] -> [B,1,d] through ``wo`` (its zero-padded
+    head rows match q's padding)."""
+    out = out[:, None].astype(dtype)
+    return jnp.einsum("bshk,hkd->bsd", out, _pad_heads(wo, out.shape[2], 0))
 
 
 # =====================================================================
@@ -375,50 +416,16 @@ def gqa_decode_paged(p, cfg, x, cache, pos_vec, block_tables):
     offset) remain undefined — callers must never duplicate positions
     within a request.
     """
-    B = x.shape[0]
-    bs = cache["k"].shape[1]
-    positions = jnp.reshape(pos_vec, (B, 1)).astype(jnp.int32)
-    q, k_new, v_new = _project_qkv(p, cfg, x, positions, rope=True)
-
-    # scatter: row b's K/V lands in its own table's block — tables of
-    # live requests never alias (allocator invariant), so rows write
-    # disjoint (block, offset) cells
-    rows = jnp.arange(B)
-    blk = block_tables[rows, positions[:, 0] // bs]
-    off = positions[:, 0] % bs
-    k = cache["k"].at[blk, off].set(k_new[:, 0].astype(cache["k"].dtype))
-    v = cache["v"].at[blk, off].set(v_new[:, 0].astype(cache["v"].dtype))
-
-    if PAGED_ATTN_IMPL != "xla":
+    q, k_new, v_new = gqa_decode_qkv(p, cfg, x, pos_vec)
+    cache = gqa_append(cache, k_new, v_new,
+                       paged_cells(block_tables, pos_vec, cache["k"].shape[1]))
+    if PAGED_ATTN_IMPL == "xla":
+        out = gqa_attend_paged(q, cache, block_tables, pos_vec)
+    else:
         from repro.kernels import ops as kops
-        out = kops.paged_attention(q[:, 0], k, v, block_tables,
-                                   positions[:, 0], impl=PAGED_ATTN_IMPL)
-        out = out[:, None].astype(x.dtype)
-        H = q.shape[2]
-        wo = _pad_heads(p["wo"], H, 0)
-        return jnp.einsum("bshk,hkd->bsd", out, wo), {"k": k, "v": v}
-
-    # gather the per-row logical KV strip: [B,T,bs,kv,hd] -> [B,T*bs,kv,hd]
-    T = block_tables.shape[1]
-    kg = k[block_tables].reshape(B, T * bs, *k.shape[2:])
-    vg = v[block_tables].reshape(B, T * bs, *v.shape[2:])
-
-    H, KV, hd = q.shape[2], kg.shape[2], cfg.head_dim
-    G = H // KV
-    qf = q.reshape(B, KV, G, hd).astype(kg.dtype)
-    s = jnp.einsum("bkgh,blkh->bkgl", qf, kg,
-                   preferred_element_type=jnp.float32)
-    s = s / math.sqrt(hd)
-
-    valid = jnp.arange(T * bs)[None, :] <= positions  # [B, T*bs]
-    s = jnp.where(valid[:, None, None, :], s, NEG_INF)
-    w = jax.nn.softmax(s, axis=-1)
-    out = jnp.einsum("bkgl,blkh->bkgh", w.astype(vg.dtype), vg,
-                     preferred_element_type=jnp.float32)
-    out = out.reshape(B, 1, H, hd).astype(x.dtype)
-    wo = _pad_heads(p["wo"], H, 0)
-    y = jnp.einsum("bshk,hkd->bsd", out, wo)
-    return y, {"k": k, "v": v}
+        out = kops.paged_attention(q, cache["k"], cache["v"], block_tables,
+                                   pos_vec, impl=PAGED_ATTN_IMPL)
+    return gqa_out(p["wo"], out, x.dtype), cache
 
 
 # Paged decode attention implementation: "xla" (gather + masked softmax,

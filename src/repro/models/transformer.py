@@ -11,6 +11,7 @@ Layers are stacked into homogeneous groups and iterated with
 """
 from __future__ import annotations
 
+import functools
 from typing import Any, Dict, Optional
 
 import jax
@@ -356,14 +357,42 @@ def _attn_decode(p, cfg, h, cache, pos, window):
     return h + y, cache
 
 
+# One layer's GQA decode block runs as three compiled programs: the
+# norm, Q/K/V with rope and the new K/V's scatter (``_gqa_pre``); the
+# attention core (``_gqa_attend*`` or the paged kernel, which stays a
+# program of its own); and ``wo`` with the residual (``_gqa_post``).
+# Eagerly the block is ~100 separate dispatches, and on a TPU each one
+# leaves the chip idle while the host issues it. Nothing is donated:
+# the caller's pool stays readable.
+@functools.partial(jax.jit, static_argnames=("cfg",))
+def _gqa_pre(ln1, pa, cfg, h, cache, pos_vec, block_tables):
+    """-> (q [B,Hp,hd], cache with each row's new K/V written: at
+    (row, pos) when ``block_tables`` is None, else through the table)."""
+    x = rms_norm(h, ln1, cfg.norm_eps)
+    q, k_new, v_new = attn.gqa_decode_qkv(pa, cfg, x, pos_vec)
+    cells = (attn.dense_cells(pos_vec) if block_tables is None else
+             attn.paged_cells(block_tables, pos_vec, cache["k"].shape[1]))
+    return q, attn.gqa_append(cache, k_new, v_new, cells)
+
+
+_gqa_attend = jax.jit(attn.gqa_attend)
+_gqa_attend_paged = jax.jit(attn.gqa_attend_paged)
+
+
+@jax.jit
+def _gqa_post(wo, h, out):
+    return h + attn.gqa_out(wo, out, h.dtype)
+
+
 def _attn_decode_multipos(p, cfg, h, cache, pos_vec):
     """Per-row-position decode (continuous batching): pos_vec [B]."""
-    x = rms_norm(h, p["ln1"], cfg.norm_eps)
     if cfg.use_mla:
+        x = rms_norm(h, p["ln1"], cfg.norm_eps)
         y, cache = attn.mla_decode_multipos(p["attn"], cfg, x, cache, pos_vec)
-    else:
-        y, cache = attn.gqa_decode_multipos(p["attn"], cfg, x, cache, pos_vec)
-    return h + y, cache
+        return h + y, cache
+    q, cache = _gqa_pre(p["ln1"], p["attn"], cfg, h, cache, pos_vec, None)
+    out = _gqa_attend(q, cache["k"], cache["v"], pos_vec)
+    return _gqa_post(p["attn"]["wo"], h, out), cache
 
 
 def _attn_decode_paged(p, cfg, h, cache, pos_vec, block_tables):
@@ -373,14 +402,20 @@ def _attn_decode_paged(p, cfg, h, cache, pos_vec, block_tables):
     Rows may share a table at distinct positions (chunked prefill's
     virtual rows) — see the multi-position append contract on
     ``repro.models.attention.gqa_decode_paged``."""
-    x = rms_norm(h, p["ln1"], cfg.norm_eps)
     if cfg.use_mla:
+        x = rms_norm(h, p["ln1"], cfg.norm_eps)
         y, cache = attn.mla_decode_paged(p["attn"], cfg, x, cache, pos_vec,
                                          block_tables)
+        return h + y, cache
+    q, cache = _gqa_pre(p["ln1"], p["attn"], cfg, h, cache, pos_vec,
+                        block_tables)
+    if attn.PAGED_ATTN_IMPL == "xla":
+        out = _gqa_attend_paged(q, cache, block_tables, pos_vec)
     else:
-        y, cache = attn.gqa_decode_paged(p["attn"], cfg, x, cache, pos_vec,
-                                         block_tables)
-    return h + y, cache
+        from repro.kernels import ops as kops
+        out = kops.paged_attention(q, cache["k"], cache["v"], block_tables,
+                                   pos_vec, impl=attn.PAGED_ATTN_IMPL)
+    return _gqa_post(p["attn"]["wo"], h, out), cache
 
 
 def _block_decode(p, cfg, h, cache, pos, *, kind, window, cross_kv, moe_path):

@@ -76,3 +76,31 @@ def test_paged_attention_pallas_compiles_at_mixtral_heads(one_chip, dtype):
     compiled = fn.lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
     assert compiled.out_info.shape == (B, H, HD)
+
+
+@pytest.mark.parametrize("rows,width", [(64, 1), (64, 65)])
+def test_paged_decode_block_programs_compile_at_mixtral_width(one_chip, rows,
+                                                              width):
+    """The paged decode block's programs around the kernel (norm, Q/K/V
+    with rope and the K/V scatter; ``wo`` and the residual), bf16, at
+    the narrowest and widest block table a served step gives."""
+    from repro.configs import get_config
+    from repro.models import transformer as tf
+    cfg = get_config("mixtral-8x7b")
+    bf = jnp.bfloat16
+    N, BS = rows * width, 16
+    pa = {"wq": _spec((D, H, HD), bf, one_chip),
+          "wk": _spec((D, KV, HD), bf, one_chip),
+          "wv": _spec((D, KV, HD), bf, one_chip),
+          "wo": _spec((H, HD, D), bf, one_chip)}
+    pool = {k: _spec((N, BS, KV, HD), bf, one_chip) for k in ("k", "v")}
+    h = _spec((rows, 1, D), bf, one_chip)
+    pre = tf._gqa_pre.lower(
+        _spec((D,), bf, one_chip), pa, cfg, h, pool,
+        _spec((rows,), jnp.int32, one_chip),
+        _spec((rows, width), jnp.int32, one_chip)).compile()
+    q, new_pool = pre.out_info
+    assert q.shape == (rows, H, HD) and new_pool["k"].shape == (N, BS, KV, HD)
+    post = tf._gqa_post.lower(pa["wo"], h,
+                              _spec((rows, H, HD), bf, one_chip)).compile()
+    assert post.out_info.shape == (rows, 1, D)
