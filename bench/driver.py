@@ -17,6 +17,34 @@ the device-trace metrics read it, while the program counters and the
 step's FLOP rate read the untraced window. After the windows, device
 memory is read, the program is freed, and a sample of the served
 requests is checked against the plain reference.
+
+What the driver calls on the program, which the program has to offer
+for any configuration:
+
+- ``repro.core.expert_store.ExpertStore``: filled by ``fill_store``
+  with ``put((layer, published expert id), {"w1", "w3", "w2"})`` for
+  each layer and held expert of the model module's layout
+  (``spec.expert_layout``), and given to ``ContinuousOffloadServer(...,
+  store=store)``.
+- ``srv.engine.caches``: per layer an ``ExpertCache``, or ``None`` for
+  a layer with no routed experts in the store. The caches that exist
+  are filled before the window (``slot_of``, ``n_slots``), their
+  ``hits``, ``misses`` and ``bytes_transferred`` are counted over it,
+  and the first one's ``gather`` warms every union width.
+- ``srv.trace.steps``: per step and layer with routed experts,
+  ``activated``, the experts the chip ran (the union, which
+  ``moe_ffn_roofline`` counts), and ``request_ids``,
+  ``request_token_idx`` and ``request_activated``: each row's routed
+  experts as published ids, 0 to ``k`` of them (a row's other experts
+  may sit on other chips). The check follows these routes; routing
+  popularity counts them; the first such layer's
+  ``request_token_idx`` gives the window's mean context.
+- ``srv._step_rows``, ``tf._attn_decode_paged`` on layer 0 of
+  ``params["layers"]`` (``oe._layer_slice``) to warm each block-table
+  width, and ``oe._grouped_ffn`` to warm each union-chunk width.
+
+What a model module (``models/<reference>.py``) provides is listed in
+``spec.py``.
 """
 from __future__ import annotations
 
@@ -118,10 +146,41 @@ class CompileCounter:
         jax.monitoring.unregister_event_duration_listener(on_duration)
 
 
+def _caches(engine) -> list:
+    """The engine's expert caches that exist (a layer with no routed
+    experts in the store may have ``None``)."""
+    return [c for c in engine.caches if c is not None]
+
+
 def _cache_counts(engine) -> Dict[str, int]:
-    return {"hits": sum(c.hits for c in engine.caches),
-            "misses": sum(c.misses for c in engine.caches),
-            "bytes": sum(c.bytes_transferred for c in engine.caches)}
+    caches = _caches(engine)
+    return {"hits": sum(c.hits for c in caches),
+            "misses": sum(c.misses for c in caches),
+            "bytes": sum(c.bytes_transferred for c in caches)}
+
+
+def fill_store(weights, layers, held):
+    """The host expert store: for each layer in ``layers`` the experts
+    ``weights.experts(layer)`` stacks, the i-th under the key (layer,
+    ``held[i]``), its published id."""
+    from repro.core.expert_store import ExpertStore
+    store = ExpertStore()
+    for l in layers:
+        ex = jax.device_get(weights.experts(l))
+        for i, e in enumerate(held):
+            store.put((l, e), {k: v[i] for k, v in ex.items()})
+        del ex
+    return store
+
+
+def popularity(records, n_layers: int, experts: int) -> np.ndarray:
+    """Routing choices [n_layers, experts] over the trace's rows, by
+    published id; a layer with no routed experts keeps a zero row."""
+    out = np.zeros((n_layers, experts), np.int64)
+    for r in records:
+        for acts in r.request_activated:
+            out[r.layer, [e for e in acts if e >= 0]] += 1
+    return out
 
 
 def _host_rss() -> int:
@@ -135,7 +194,6 @@ def run(cell: dict, seed: int, seconds: float, trace: bool, *, t0: float,
     reference is also read at the same positions (calibration only)."""
     from repro.configs.base import ModelConfig
     from repro.core import offload_engine as oe
-    from repro.core.expert_store import ExpertStore
     from repro.models import attention as attn
     from repro.models import transformer as tf
     from repro.serving import ContinuousOffloadServer
@@ -154,17 +212,15 @@ def run(cell: dict, seed: int, seconds: float, trace: bool, *, t0: float,
     mcfg = ModelConfig(**c["program"])
     sv = c["serving"]
     dims = model.dims(c)
+    layers, held, experts = bspec.expert_layout(model, c)
 
     # ---- weights: non-expert tree on the device, experts to the host
     weights = model.Weights(c, seed, tr["routing_zipf_s"])
     params = weights.params()
-    store = ExpertStore()
-    for l in range(dims.L):
-        ex = jax.device_get(weights.experts(l))
-        for e in range(dims.E):
-            store.put((l, e), {k: v[e] for k, v in ex.items()})
-        del ex
+    store = fill_store(weights, layers, held)
     t_built = time.perf_counter()
+    report(f"host store: {len(store.keys())} experts, "
+           f"{store.total_nbytes()} bytes")
 
     plan = loadgen.Plan(tr, seed, dims.V)
     C = plan.clients
@@ -177,6 +233,7 @@ def run(cell: dict, seed: int, seconds: float, trace: bool, *, t0: float,
         ffn_impl=sv["ffn_impl"], kv_block_size=bs,
         kv_num_blocks=C * blocks, prefill_chunk=tr["prefill_chunk"])
     eng = srv.engine
+    caches = _caches(eng)
     rows = srv._step_rows
 
     # ---- compile the shapes that depend on the traffic --------------
@@ -242,14 +299,14 @@ def run(cell: dict, seed: int, seconds: float, trace: bool, *, t0: float,
         submit(client)
     for _ in range(10_000):
         if all(len(times[rid]) > 0 for rid in open_rids) and all(
-                len(ca.slot_of) == ca.n_slots for ca in eng.caches):
+                len(ca.slot_of) == ca.n_slots for ca in caches):
             break
         step()
     else:
         raise RuntimeError("the server never reached a full cache")
 
     x0 = jnp.zeros((rows, dims.d), eng.dtype)
-    for ca in eng.caches[:1]:        # every union-chunk width
+    for ca in caches[:1]:            # every union-chunk width
         ids = sorted(ca.slot_of)
         for U in range(1, len(ids) + 1):
             w = ca.gather(ids[:U])
@@ -281,7 +338,7 @@ def run(cell: dict, seed: int, seconds: float, trace: bool, *, t0: float,
         t_end = step_log[-1][1]
         counts1 = _cache_counts(eng)
         records = srv.trace.steps[rec0:]
-        pos = [t for r in records if r.layer == 0
+        pos = [t for r in records if r.layer == layers[0]
                for t in r.request_token_idx]
         return types.SimpleNamespace(
             t_start=t_start, t_end=t_end, window_s=t_end - t_start,
@@ -309,14 +366,11 @@ def run(cell: dict, seed: int, seconds: float, trace: bool, *, t0: float,
            f"RSS {host_rss}")
     cc.close()
 
-    popularity = np.zeros((dims.L, dims.E), np.int64)
-    for r in plain.records:
-        for acts in r.request_activated:
-            popularity[r.layer, list(acts)] += 1
     ctx = types.SimpleNamespace(
         **{k: v for k, v in vars(plain).items() if k != "records"},
         setup_s=plain.t_start - t0, build_s=t_built - t0,
-        chips=len(devs), times=times, popularity=popularity, dims=dims,
+        chips=len(devs), times=times, expert_layers=layers,
+        popularity=popularity(plain.records, dims.L, experts), dims=dims,
         cfg=c, model=model, slots=sv["cache_slots"], rows=rows,
         block_size=bs, peaks=bspec.peaks(devs[0].device_kind),
         traced=traced, profile=prof)
@@ -332,7 +386,7 @@ def run(cell: dict, seed: int, seconds: float, trace: bool, *, t0: float,
     served.clear()
     statuses = [r.status for r in reqs.values() if r.done]
     preempted = srv.kv_preemptions
-    del srv, eng, params, store, p0, pos0, plain
+    del srv, eng, caches, params, store, p0, pos0, plain
     gc.collect()
 
     result = {"ctx": ctx, "attempted": len(reqs), "preempted": preempted,
@@ -406,7 +460,8 @@ def _pick(reqs: Dict[int, object], budget: int, seed: int) -> List[int]:
 
 def _routes(trace, rids: List[int], dims) -> Dict[int, np.ndarray]:
     """Served experts [positions, L, k] of each request, from the
-    program's routing trace."""
+    program's routing trace, padded with -1 where a row lists fewer than
+    ``k`` (or a layer has no routed experts)."""
     want = set(rids)
     rows: Dict[int, dict] = {rid: {} for rid in rids}
     for s in trace.steps:
@@ -416,9 +471,9 @@ def _routes(trace, rids: List[int], dims) -> Dict[int, np.ndarray]:
     out = {}
     for rid, got in rows.items():
         n = 1 + max(t for t, _ in got) if got else 0
-        r = np.zeros((n, dims.L, dims.k), np.int64)
+        r = np.full((n, dims.L, dims.k), -1, np.int64)
         for (tok, layer), acts in got.items():
-            r[tok, layer] = acts
+            r[tok, layer, :len(acts)] = acts
         out[rid] = r
     return out
 

@@ -1,14 +1,16 @@
 """Closed-loop request plan from a traffic file and a seed.
 
-Every seed gets the same set of sizes: ``pool`` prompt lengths and
-``pool`` output lengths at evenly spaced quantiles of their lognormal
-laws, clipped to ``[min, max]``. A law is given by the mean and the
-standard deviation that the traffic file's ``source`` publishes; the
-lognormal is the one with that mean and deviation. The seed only shuffles the order of
-each set (and so their pairing) and draws the prompt token ids. The
+Every seed gets the same sizes in the same order: ``pool`` prompt
+lengths and ``pool`` output lengths at evenly spaced quantiles of their
+lognormal laws, clipped to ``[min, max]``, each set in one fixed
+shuffled order (and so one pairing). A window holds only the first few
+requests of a client, so an order drawn from the seed would change the
+work a run does. A law is given by the mean and the standard deviation
+that the traffic file's ``source`` publishes; the lognormal is the one
+with that mean and deviation. The seed draws the prompt token ids. The
 first request of each client (the one the server is warmed with) has
-the median prompt and a fixed output quantile, the same for every
-seed, so set-up does the same work on every seed.
+the median prompt and a fixed output quantile, so set-up too does the
+same work on every seed.
 
 A client sends its next request when its last one completes; the
 clients share one sequence: the warm-up requests, then the pool,
@@ -21,6 +23,9 @@ from statistics import NormalDist
 from typing import List, Tuple
 
 import numpy as np
+
+# The one order of the size sets, the same for every run's seed.
+ORDER_SEED = 1
 
 
 def lognormal(law: dict) -> Tuple[float, float]:
@@ -52,7 +57,7 @@ class Plan:
         pool = int(traffic["pool"])
         prompts = lognormal_quantiles(traffic["prompt_len"], pool)
         outs = lognormal_quantiles(traffic["output_len"], pool)
-        rng = np.random.default_rng([seed, 1])
+        rng = np.random.default_rng(ORDER_SEED)
         self.pool: List[Tuple[int, int]] = [
             (prompts[i], outs[j]) for i, j in
             zip(rng.permutation(pool), rng.permutation(pool))]

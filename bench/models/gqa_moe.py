@@ -24,6 +24,15 @@ where ``t`` is the token's unit vector. ``m`` is calibrated so that the
 top-k of that sum picks expert e with the Zipf share (m = 0 for
 ``zipf_s == 0``). Routing then depends on the token id alone.
 
+Greedy stream. Plain random weights decode greedily into a short
+cycle of tokens whose length, and so the experts it keeps using, the
+seed decides. So the head adds to each token's column ``SUCCESSOR_GAIN``
+times the random part of its predecessor's embedding, the predecessors
+taken along one seeded cycle through the whole vocabulary: the token
+after x is then succ(x) by a wide margin at the published widths, a
+request's answer is a run of distinct tokens, and its routing is the
+Zipf law's, token by token, on every seed.
+
 The reference imports nothing of the program. It recomputes the same
 weights from the seed, bit for bit (made by the same jitted calls in
 the same matmul precision), then runs one layer at a time and one
@@ -48,6 +57,9 @@ F32 = jnp.float32
 # sized for (only the gate temperature depends on it, not the top-k).
 SKEW_NORM = 0.125
 ROUTER_GAIN = 4.0
+# The head's weight on the predecessor's embedding, beside its random
+# columns of the same scale (see "Greedy stream" above).
+SUCCESSOR_GAIN = 2.0
 # Largest finite float8_e4m3fn value: the control's per-channel scale.
 FP8_MAX = 448.0
 PAD = 256  # reference sequences are padded to a multiple of this
@@ -174,11 +186,15 @@ def _make_params(m: Dims, keys, W, offsets):
         router = (W[:, :1] * offsets[l][None, :] / (amp * ROUTER_GAIN)
                   + W[:, 1:] @ q * (math.sqrt(m.E) / (amp * ROUTER_GAIN)))
         layers.append({"a": a, "router": router})
+    embed = _proj_out(embed, W)
+    # the successor head: the token after x is succ(x), one cycle
+    # through the whole vocabulary in a seeded order
+    order = jax.random.permutation(jax.random.fold_in(kt, 2), m.V)
+    pred = jnp.zeros((m.V,), jnp.int32).at[order].set(jnp.roll(order, 1))
+    unembed = _proj_in(unembed, W) + SUCCESSOR_GAIN * embed[pred].T
     t = jax.random.normal(jax.random.fold_in(kb, 1), (m.V, m.E), F32)
     t = t / jnp.linalg.norm(t, axis=1, keepdims=True)
-    embed = (_proj_out(embed, W) + amp * W[:, 0][None, :]
-             + amp * t @ W[:, 1:].T)
-    unembed = _proj_in(unembed, W)
+    embed = embed + amp * W[:, 0][None, :] + amp * t @ W[:, 1:].T
     bf = jnp.bfloat16
     ones = jnp.ones((m.L, m.d), bf)
     return {
@@ -355,6 +371,9 @@ def reference_logits(weights: Weights, seqs: Sequence[Sequence[int]],
         mask = np.zeros((m.L, S, m.E), bool)
         if follow:
             r = np.asarray(served[i])
+            # every served row here lists k experts: a -1 would mark
+            # expert E - 1
+            assert (r >= 0).all(), "a served route lists fewer than k"
             for l in range(m.L):
                 np.put_along_axis(mask[l, :lens[i]], r[:, l], True, -1)
         masks.append(mask)

@@ -92,8 +92,9 @@ def main(argv=None) -> int:
     line = result_line(spec, cell, res, bool(args.trace))
     ctx = res["ctx"]
     if args.trace:
-        for l, row in enumerate(ctx.popularity):
-            _err(f"routing popularity, layer {l}: {row.tolist()}")
+        for l in ctx.expert_layers:
+            _err(f"routing popularity, layer {l}: "
+                 f"{ctx.popularity[l].tolist()}")
     _err(f"host peak RSS bytes {res['host_rss']}; set-up "
          f"{ctx.setup_s!r} s (weights built at {ctx.build_s!r} s)")
     gaps = [b - a for ts in ctx.times.values() for a, b in zip(ts, ts[1:])

@@ -4,6 +4,19 @@ A configuration is ``configs/<config>.json`` (its published sizes, the
 cut, and the program's settings) with the plain reference it names in
 ``models/<reference>.py``; a traffic mix is ``traffic/<traffic>.json``;
 a metric is ``metrics/<name>.py``. Nothing here knows a cell by name.
+
+A model module provides, for the driver and the metrics:
+
+- ``dims(c)``: a record with at least ``d``, ``E``, ``k``, ``V`` and
+  ``L`` (the model's layers), and the widths its metrics read;
+- ``token_flops(c, context)``: model FLOPs of one token;
+- ``Weights(c, seed, zipf_s)``, with ``params()``, the non-expert tree
+  as the program takes it, and ``experts(layer)``, the held experts of
+  one layer stacked as ``w1``, ``w3`` [n, d, F] and ``w2`` [n, F, d];
+- ``reference_logits(weights, seqs, served, tie, fp8=False)``: the
+  plain reference, following served routes [S, L, k] in which -1 pads
+  a row that lists fewer than ``k`` experts;
+- optionally ``layout(c)``: see ``expert_layout``.
 """
 from __future__ import annotations
 
@@ -59,6 +72,19 @@ def model(cfg: dict):
     """The weight maker and plain reference a configuration names."""
     ref = cfg["reference"]
     return load_module(BENCH / "models" / f"{ref}.py", f"bench_model_{ref}")
+
+
+def expert_layout(model, cfg: dict):
+    """``(layers, held, experts)``: the indices of the layers whose
+    routed experts live in the host store, the published ids of the
+    experts this chip holds (in the order ``Weights.experts`` stacks
+    them), and the published number of experts per layer. A model
+    module's ``layout(cfg)`` gives them; without one, every layer holds
+    every one of ``dims(cfg).E`` experts."""
+    if hasattr(model, "layout"):
+        return model.layout(cfg)
+    d = model.dims(cfg)
+    return list(range(d.L)), list(range(d.E)), d.E
 
 
 def metric_reader(name: str):
