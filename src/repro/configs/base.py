@@ -7,7 +7,7 @@ frozen dataclasses so they can be hashed into jit static args.
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional, Tuple
+from typing import Any, Optional, Tuple
 
 Family = str  # "dense" | "moe" | "ssm" | "hybrid" | "encdec" | "vlm"
 
@@ -37,6 +37,12 @@ class ModelConfig:
     use_mla: bool = False
     kv_lora_rank: int = 0
     qk_rope_dim: int = 64
+    q_lora_rank: int = 0  # 0: the query is one projection of x
+    # YaRN ``rope_scaling`` as published (keys ``factor``,
+    # ``original_max_position_embeddings``, ``beta_fast``, ``beta_slow``,
+    # ``mscale``, ``mscale_all_dim``), as a tuple of (key, value) pairs
+    # so the config stays hashable; () is plain RoPE
+    rope_scaling: Tuple[Tuple[str, Any], ...] = ()
 
     # --- MoE ---
     num_experts: int = 0
@@ -45,6 +51,16 @@ class ModelConfig:
     moe_d_ff: Optional[int] = None  # per-expert ffn dim (defaults to d_ff)
     capacity_factor: float = 1.25
     moe_every: int = 1  # layer i is MoE iff i % moe_every == moe_every-1
+    first_k_dense_replace: int = 0  # layers below it are dense (d_ff)
+    # routing: "greedy" is top-k of the router's softmax,
+    # "group_limited_greedy" top-k inside the topk_group of n_group
+    # groups whose best expert scores highest; the k weights are
+    # renormalised (norm_topk_prob) or scaled by routed_scaling_factor
+    topk_method: str = "greedy"
+    n_group: int = 1
+    topk_group: int = 1
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 1.0
 
     # --- SSM (Mamba2 / SSD) ---
     ssm_state: int = 0
@@ -70,6 +86,9 @@ class ModelConfig:
 
     # ------------------------------------------------------------------
     def __post_init__(self):
+        if isinstance(self.rope_scaling, dict):  # as a config.json gives it
+            object.__setattr__(self, "rope_scaling",
+                               tuple(sorted(self.rope_scaling.items())))
         if self.head_dim is None:
             hd = self.d_model // max(self.num_heads, 1)
             object.__setattr__(self, "head_dim", hd)
@@ -91,7 +110,8 @@ class ModelConfig:
         return self.d_inner // self.ssm_headdim
 
     def has_moe(self, i: int) -> bool:
-        return self.is_moe and (i % self.moe_every == self.moe_every - 1)
+        return (self.is_moe and i >= self.first_k_dense_replace
+                and i % self.moe_every == self.moe_every - 1)
 
     def layer_kind(self, i: int) -> str:
         """'attn' or 'ssm' mixer for decoder layer i."""
@@ -119,8 +139,11 @@ class ModelConfig:
 
         def attn_params() -> int:
             if self.use_mla:
-                r, rd = self.kv_lora_rank, self.qk_rope_dim
-                p = d * self.num_heads * (hd + rd)          # q proj
+                r, rd, rq = self.kv_lora_rank, self.qk_rope_dim, self.q_lora_rank
+                if rq:                                       # q LoRA
+                    p = d * rq + rq * self.num_heads * (hd + rd)
+                else:
+                    p = d * self.num_heads * (hd + rd)      # q proj
                 p += d * (r + rd)                            # kv_a
                 p += r * self.num_heads * (hd + hd)          # kv_b (k_nope + v)
                 p += self.num_heads * hd * d                 # o
@@ -224,6 +247,9 @@ def reduced(cfg: ModelConfig, *, layers: int = 2, d_model: int = 256,
         kv = num_heads
     n_exp = min(cfg.num_experts, experts) if cfg.is_moe else 0
     top_k = min(cfg.num_experts_per_tok, n_exp) if n_exp else 0
+    # keep the routing groups where the cut experts still fill them
+    groups = (n_exp and n_exp % cfg.n_group == 0
+              and cfg.topk_group * (n_exp // cfg.n_group) >= top_k)
     attn_every = min(cfg.attn_every, 2) if cfg.attn_every else 0
     return dataclasses.replace(
         cfg,
@@ -240,6 +266,10 @@ def reduced(cfg: ModelConfig, *, layers: int = 2, d_model: int = 256,
         num_experts_per_tok=top_k,
         num_shared_experts=min(cfg.num_shared_experts, 1),
         kv_lora_rank=64 if cfg.use_mla else 0,
+        q_lora_rank=d_model if cfg.q_lora_rank else 0,
+        first_k_dense_replace=min(cfg.first_k_dense_replace, layers - 1),
+        n_group=cfg.n_group if groups else 1,
+        topk_group=cfg.topk_group if groups else 1,
         qk_rope_dim=32 if cfg.use_mla else cfg.qk_rope_dim,
         ssm_state=min(cfg.ssm_state, 32) if cfg.ssm_state else 0,
         ssm_headdim=32 if cfg.ssm_state else cfg.ssm_headdim,

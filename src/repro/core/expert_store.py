@@ -108,15 +108,18 @@ class ExpertStore:
     def from_params(cls, params, cfg, *, quant: str = "none") -> "ExpertStore":
         """Strip the per-layer expert weights out of a stacked model
         param tree into a store. Expects ``params['layers']['moe']``
-        with stacked experts [L, E, ...]."""
+        with stacked experts [L, E, ...]: the model's last L layers
+        (after its leading dense layers), keyed by model layer."""
         store = cls(quant=quant)
         experts = params["layers"]["moe"]["experts"]
         L = experts["w1"].shape[0]
         E = experts["w1"].shape[1]
+        first = cfg.num_layers - L
         w1 = np.asarray(experts["w1"])
         w2 = np.asarray(experts["w2"])
         w3 = np.asarray(experts["w3"])
         for l in range(L):
             for e in range(E):
-                store.put((l, e), {"w1": w1[l, e], "w3": w3[l, e], "w2": w2[l, e]})
+                store.put((first + l, e),
+                          {"w1": w1[l, e], "w3": w3[l, e], "w2": w2[l, e]})
         return store

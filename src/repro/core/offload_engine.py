@@ -31,8 +31,9 @@ from repro.core.prefetch import (LearnedPredictor, MarkovPredictor,
 from repro.core.trace import TraceRecorder
 from repro.core.transfer_engine import TransferEngine
 from repro.kernels import ops
+from repro.models import moe as moe_lib
 from repro.models import transformer as tf
-from repro.models.layers import rms_norm, sinusoidal_positions
+from repro.models.layers import rms_norm, sinusoidal_positions, swiglu
 
 
 def _layer_slice(tree, i):
@@ -75,7 +76,7 @@ def init_offloaded_params(cfg, key, *, quant: str = "none"):
 
     k_exp = jax.random.fold_in(key, 2**31 - 1)  # apart from init_params'
     store = ExpertStore(quant=quant)
-    for l in range(cfg.num_layers):
+    for l in filter(cfg.has_moe, range(cfg.num_layers)):
         k_l = jax.random.fold_in(k_exp, l)
         for e in range(cfg.num_experts):
             store.put((l, e), one_expert(jax.random.fold_in(k_l, e)))
@@ -96,6 +97,19 @@ def _grouped_ffn(xf, w1, w3, w2, comb, *, impl: str = "xla"):
     x_e = jnp.broadcast_to(xf[None], (w1.shape[0],) + xf.shape)
     out = ops.moe_ffn(x_e, w1, w3, w2, impl=impl)
     return jnp.einsum("ubd,bu->bd", out, comb)
+
+
+@functools.partial(jax.jit, static_argnames=("eps",))
+def _dense_ffn(ln2, mlp, h, *, eps: float):
+    """A dense layer's FFN with its norm and residual, one program."""
+    return h + swiglu(mlp, rms_norm(h, ln2, eps))
+
+
+@jax.jit
+def _shared_ffn(shared, x, h):
+    """The shared experts (one SwiGLU of their summed width) on the
+    layer's normed input, added to the residual, one program."""
+    return h + swiglu(shared, x)
 
 
 def _batch_union(ids: np.ndarray, probs: np.ndarray,
@@ -160,7 +174,15 @@ class OffloadEngine:
         """``params`` either holds the stacked experts, which move to a
         new host ``ExpertStore``, or comes with the ``store`` that
         already holds them (``init_offloaded_params``). Either way the
-        device keeps only the non-expert parameters."""
+        device keeps only the non-expert parameters.
+
+        The experts this engine computes are the ones the store holds:
+        the router ranks all ``num_experts``, and a row's choices that
+        the store lacks (experts held on other chips of an
+        expert-parallel deployment) are left out of its result. Every
+        MoE layer holds the same ids. Layers below
+        ``first_k_dense_replace`` are dense (``params["dense_layers"]``)
+        and have no cache (``caches[l] is None``) and no trace record."""
         assert cfg.is_moe, "offloading targets MoE experts"
         if prefetch not in (None, "spec", "markov", "learned"):
             raise ValueError(
@@ -185,9 +207,6 @@ class OffloadEngine:
             if any(s < 1 for s in slots):
                 raise ValueError(
                     f"per-layer cache_slots must all be >= 1, got {slots}")
-        # per-layer budgets (beyond paper: skewed layers need fewer slots)
-        self.slots = [max(1, min(s, cfg.num_experts)) for s in slots]
-        self.cache_slots = sum(self.slots) / cfg.num_layers
         self.prefetch_mode = prefetch
         self.trace = trace if trace is not None else TraceRecorder()
         # one injector shared by caches, transfer engine and tier
@@ -198,14 +217,30 @@ class OffloadEngine:
                       else ExpertStore.from_params(params, cfg, quant=quant))
         self.params = _without_experts(params)
         self.dtype = self.params["embed"].dtype
+        self.n_dense = (jax.tree.leaves(params["dense_layers"])[0].shape[0]
+                        if "dense_layers" in params else 0)
+        held = {l: [] for l in range(self.n_dense, cfg.num_layers)}
+        for l, e in self.store.keys():
+            held[l].append(e)
+        sets = {tuple(sorted(v)) for v in held.values()}
+        if len(sets) != 1 or () in sets:
+            raise ValueError("every MoE layer must hold the same non-empty "
+                             f"set of experts; the store holds {held}")
+        self.held = sets.pop()
+        self._held_mask = np.zeros(cfg.num_experts, bool)
+        self._held_mask[list(self.held)] = True
+        # per-layer budgets (beyond paper: skewed layers need fewer slots)
+        self.slots = [max(1, min(s, len(self.held))) for s in slots]
+        self.cache_slots = (sum(self.slots[self.n_dense:])
+                            / (cfg.num_layers - self.n_dense))
 
         d, ff = cfg.d_model, cfg.expert_d_ff
         shapes = {"w1": (d, ff), "w3": (d, ff), "w2": (ff, d)}
         pkw = dict(policy_kw or {})
         if policy == "learned" and learned_model is not None:
             pkw.setdefault("model", learned_model)
-        self.caches: List[ExpertCache] = []
-        for l in range(cfg.num_layers):
+        self.caches: List[Optional[ExpertCache]] = [None] * self.n_dense
+        for l in range(self.n_dense, cfg.num_layers):
             pol = (policy_factory(l) if policy_factory is not None
                    else make_policy(policy, self.slots[l], **pkw))
             self.caches.append(ExpertCache(l, self.slots[l], pol,
@@ -213,7 +248,7 @@ class OffloadEngine:
                                            faults=self.faults))
 
         mb = ModelBytes.from_config(cfg)
-        eb = self.store.expert_nbytes((0, 0))
+        eb = self.store.expert_nbytes(self.store.keys()[0])
         mb = ModelBytes(**{**mb.__dict__, "expert_bytes": eb})
         self.cost = CostModel(hw or HardwareProfile.a6000_pcie4(), mb,
                               overlap=overlap)
@@ -256,12 +291,23 @@ class OffloadEngine:
             tiers.trace = self.trace
         for key in self.store.keys():
             tiers.register_expert(key, self.store.expert_nbytes(key))
-        for c in self.caches:
+        for c in self.moe_caches:
             c.tiers = tiers
         if self.faults is not None and getattr(tiers, "queue", None) is not None:
             # KV parks / disk spills ride the same injector (their
             # chains never abandon — a parked snapshot is the only copy)
             tiers.queue.faults = self.faults
+
+    @property
+    def moe_caches(self) -> List[ExpertCache]:
+        """The caches of the layers with routed experts."""
+        return [c for c in self.caches if c is not None]
+
+    def _block(self, l: int):
+        """Layer ``l``'s device parameters (the dense stack first)."""
+        if l < self.n_dense:
+            return _layer_slice(self.params["dense_layers"], l)
+        return _layer_slice(self.params["layers"], l - self.n_dense)
 
     # ------------------------------------------------------------------
     def init_state(self, batch: int, cache_len: int):
@@ -293,15 +339,29 @@ class OffloadEngine:
         as a fresh ``generate`` call."""
         self._prev_acts = {}
 
+    def _prefetch_ahead(self, layer: int, guess: Sequence[int],
+                        pending: Dict) -> int:
+        """Prefetch a guess for ``layer`` into its cache, record it in
+        ``pending`` and, under overlap, issue its copies. Ids the store
+        does not hold (experts of other chips) are dropped from the
+        guess. Returns the number of experts moved."""
+        guess = tuple(e for e in guess if self._held_mask[e])
+        cache = self.caches[layer]
+        moved = cache.prefetch(guess)
+        pending[layer] = (guess, tuple(moved),
+                          dict(cache.last_prefetch_outcomes))
+        if self.overlap:
+            self._issue_transfers(layer, moved, demand=False,
+                                  outcomes=cache.last_prefetch_outcomes
+                                  or None)
+        return len(moved)
+
     # ------------------------------------------------------------------
     def _route(self, p_l, x) -> Tuple[np.ndarray, np.ndarray]:
-        """x [B,1,d] -> (top ids [B,k], top probs [B,k]) on host."""
+        """x [B,1,d] -> (ids [B,k], weights [B,k]) on host, by the
+        model's router (``moe.route``) over all ``num_experts``."""
         logits = np.asarray((x.astype(jnp.float32) @ p_l["moe"]["router"])[:, 0, :])
-        k = self.cfg.num_experts_per_tok
-        ids = np.argsort(-logits, axis=-1)[:, :k]
-        top = np.take_along_axis(logits, ids, axis=-1)
-        top = np.exp(top - top.max(axis=-1, keepdims=True))
-        probs = top / top.sum(axis=-1, keepdims=True)
+        probs, ids = moe_lib.route(logits, self.cfg, xp=np)
         return ids, probs
 
     def _issue_transfers(self, layer: int, eids: Sequence[int], *,
@@ -355,14 +415,23 @@ class OffloadEngine:
         degradation flags land in the trace for quality attribution.
         """
         cfg = self.cfg
+        E = cfg.num_experts
         x = rms_norm(h, p_l["ln2"], cfg.norm_eps)
-        with jax.profiler.TraceAnnotation("engine.route", layer=layer):
+        with jax.profiler.TraceAnnotation("engine.route", layer=layer) as sp:
             ids, probs = self._route(p_l, x)   # [B,k]
+            act = np.asarray(active, bool)
+            here = self._held_mask[ids] & act[:, None]
+            sp.set_metadata(held=int(here.sum()),
+                            routed=int(act.sum()) * ids.shape[1])
         B = ids.shape[0]
+        # choices held on other chips go to a column E that no chunk
+        # holds: out of the union, zero in every combine
+        cols = np.where(self._held_mask[ids], ids, E)
 
         # union of needed experts over ACTIVE rows, most-weighted first
         # (deterministic; first-occurrence order breaks weight ties)
-        union, weight_of = _batch_union(ids, probs, active, cfg.num_experts)
+        union, weight_of = _batch_union(cols, probs, active, E + 1)
+        union = [e for e in union if e != E]
 
         cache = self.caches[layer]
         cache_before = cache.cached_ids()
@@ -406,8 +475,7 @@ class OffloadEngine:
             with jax.profiler.TraceAnnotation(
                     "engine.ffn", layer=layer, experts=len(comp), rows=B):
                 w = cache.gather(comp)
-                comb = _combine_matrix(comp, ids, probs, active,
-                                       cfg.num_experts)
+                comb = _combine_matrix(comp, cols, probs, active, E + 1)
                 if scale is not None:
                     comb = (comb * scale[:, None]).astype(np.float32)
                 y = y + _grouped_ffn(x[:, 0, :], w["w1"], w["w3"], w["w2"],
@@ -453,14 +521,14 @@ class OffloadEngine:
                 self._step_fault_stall_s += extra
         self.exposed_transfer_s += stall_s
         if "shared" in p_l["moe"]:
-            s = p_l["moe"]["shared"]
-            xs = x
-            h = h + ((jax.nn.silu(xs @ s["w1"]) * (xs @ s["w3"])) @ s["w2"])
+            with jax.profiler.TraceAnnotation("engine.shared", layer=layer,
+                                              rows=B):
+                h = _shared_ffn(p_l["moe"]["shared"], x, h)
 
         # per-request attribution (slice of the union)
         req_ids = tuple(int(prompt_ids[b]) for b in range(B) if active[b])
         req_tok = tuple(int(token_indices[b]) for b in range(B) if active[b])
-        req_act = tuple(tuple(sorted(int(e) for e in ids[b]))
+        req_act = tuple(tuple(sorted(int(e) for e in ids[b][here[b]]))
                         for b in range(B) if active[b])
         # per-row degradation flags (aligned with req_ids): a row is
         # degraded at this layer iff one of ITS routed experts dropped
@@ -565,7 +633,7 @@ class OffloadEngine:
                 self.faults.now = self.sim_time
 
             for l in range(cfg.num_layers):
-                p_l = _layer_slice(params["layers"], l)
+                p_l = self._block(l)
                 with jax.profiler.TraceAnnotation("engine.attention", layer=l):
                     if block_tables is None:
                         h, state["layers"][l] = tf._attn_decode_multipos(
@@ -576,23 +644,22 @@ class OffloadEngine:
                             block_tables)
 
                 # --- speculative guess for layer l+1 (paper §3.2) ---------
-                if self.spec is not None and l + 1 < cfg.num_layers:
-                    p_next = _layer_slice(params["layers"], l + 1)
+                if (self.spec is not None and l + 1 < cfg.num_layers
+                        and self.caches[l + 1] is not None):
+                    p_next = self._block(l + 1)
                     guess = self.spec.guess(h[act_rows], p_next["ln2"],
                                             p_next["moe"]["router"])
-                    moved = self.caches[l + 1].prefetch(guess)
-                    step_prefetch += len(moved)
-                    pending[l + 1] = (guess, tuple(moved),
-                                      dict(self.caches[l + 1]
-                                           .last_prefetch_outcomes))
-                    if self.overlap:
-                        # issued before layer l's MoE computes: the copy
-                        # has layer l's compute window to hide under
-                        self._issue_transfers(
-                            l + 1, moved, demand=False,
-                            outcomes=self.caches[l + 1].last_prefetch_outcomes
-                            or None)
+                    # issued before layer l's MoE computes: under overlap
+                    # the copy has layer l's compute window to hide under
+                    step_prefetch += self._prefetch_ahead(l + 1, guess,
+                                                          pending)
 
+                if self.caches[l] is None:
+                    with jax.profiler.TraceAnnotation("engine.dense_ffn",
+                                                      layer=l):
+                        h = _dense_ffn(p_l["ln2"], p_l["mlp"], h,
+                                       eps=cfg.norm_eps)
+                    continue
                 pg, pm, po = pending.get(l, ((), (), {}))
                 with jax.profiler.TraceAnnotation("engine.moe", layer=l):
                     h, acts, misses, req_deg = self._moe_offloaded(
@@ -606,7 +673,7 @@ class OffloadEngine:
                     if self.learned is not None:
                         # keep the learned feature walk aligned with training
                         self.learned.observe(l, acts)
-                    if l > 0:
+                    if l > self.n_dense:
                         predictor.update(l - 1, self._prev_acts.get(l - 1, ()),
                                          acts)
                     if l + 1 < cfg.num_layers:
@@ -617,20 +684,11 @@ class OffloadEngine:
                         # set: train/predict skew that wasted the learned
                         # transitions whenever consecutive tokens routed
                         # differently — regression-tested.)
-                        guess = predictor.predict(l, acts)
-                        moved = self.caches[l + 1].prefetch(guess)
-                        step_prefetch += len(moved)
-                        pending[l + 1] = (guess, tuple(moved),
-                                          dict(self.caches[l + 1]
-                                               .last_prefetch_outcomes))
-                        if self.overlap:
-                            # predicted AFTER layer l's MoE (the clock has
-                            # advanced past it): the copy hides under layer
-                            # l+1's attention + FFN compute
-                            self._issue_transfers(
-                                l + 1, moved, demand=False,
-                                outcomes=self.caches[l + 1]
-                                .last_prefetch_outcomes or None)
+                        # predicted AFTER layer l's MoE (the clock has
+                        # advanced past it): under overlap the copy hides
+                        # under layer l+1's attention + FFN compute
+                        step_prefetch += self._prefetch_ahead(
+                            l + 1, predictor.predict(l, acts), pending)
                 self._prev_acts[l] = acts
 
             with jax.profiler.TraceAnnotation("engine.logits"):
@@ -749,9 +807,10 @@ class OffloadEngine:
         passes its paged pool's peak block occupancy * block_size);
         the bare engine's dense per-call state is transient and priced
         at 0 by default."""
-        hits = sum(c.hits for c in self.caches)
-        misses = sum(c.misses for c in self.caches)
-        pre = sum(c.prefetches for c in self.caches)
+        caches = self.moe_caches
+        hits = sum(c.hits for c in caches)
+        misses = sum(c.misses for c in caches)
+        pre = sum(c.prefetches for c in caches)
         prec, rec = self.trace.cache_precision_recall()
         sp, sr = self.trace.spec_precision_recall()
         s = {
@@ -759,7 +818,7 @@ class OffloadEngine:
             "hit_rate": hits / max(hits + misses, 1),
             "cache_precision": prec, "cache_recall": rec,
             "spec_precision": sp, "spec_recall": sr,
-            "bytes_transferred": sum(c.bytes_transferred for c in self.caches),
+            "bytes_transferred": sum(c.bytes_transferred for c in caches),
             "decode_steps": self._steps_done,
             # overlap pipeline accounting: DMA seconds issued vs the
             # fraction the simulated clock actually saw (== 1.0 on the
@@ -783,9 +842,9 @@ class OffloadEngine:
             # health/degradation summary (keys absent without an
             # injector so pre-fault stats stay unchanged)
             s.update(self.faults.stats())
-            s["fetch_failures"] = sum(c.fetch_failures for c in self.caches)
+            s["fetch_failures"] = sum(c.fetch_failures for c in caches)
             s["corrupt_refetches"] = sum(c.corrupt_refetches
-                                         for c in self.caches)
+                                         for c in caches)
             s["degraded_tokens"] = self.degraded_tokens
             s["degraded_token_frac"] = (self.degraded_tokens
                                         / max(self.tokens_done, 1))

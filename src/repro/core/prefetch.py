@@ -25,6 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.learned import LayerState, LearnedModel
+from repro.models import moe as moe_lib
 from repro.models.layers import rms_norm
 
 
@@ -33,15 +34,15 @@ class SpeculativePrefetcher:
 
     def __init__(self, cfg):
         self.cfg = cfg
-        self.k = cfg.num_experts_per_tok
 
     def guess(self, h_after_attn, next_ln_w, next_router) -> Tuple[int, ...]:
         """h_after_attn [B,1,d] (layer l, post-attention residual);
         next_ln_w / next_router: layer l+1's pre-FFN norm + gate weights.
-        Returns the union of per-sequence top-k guesses."""
+        Returns the union of per-sequence guesses by the model's router
+        (``moe.route``)."""
         x = rms_norm(h_after_attn, next_ln_w, self.cfg.norm_eps)
         logits = np.asarray((x.astype(jnp.float32) @ next_router)[:, 0, :])
-        ids = np.argsort(-logits, axis=-1)[:, :self.k]  # [B, k]
+        _, ids = moe_lib.route(logits, self.cfg, xp=np)  # [B, k]
         # np.unique == sorted set union (vectorized over the batch)
         return tuple(int(e) for e in np.unique(ids))
 

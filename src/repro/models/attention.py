@@ -19,6 +19,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.models.layers import apply_rope, dense_init, rope_cos_sin
 from repro.models.sharding import constrain, padded_count
@@ -74,11 +75,10 @@ def init_gqa(key, cfg, dtype, *, kv_heads: Optional[int] = None):
 
 def init_mla(key, cfg, dtype):
     d, H, hd = cfg.d_model, cfg.num_heads, cfg.head_dim
-    r, rd = cfg.kv_lora_rank, cfg.qk_rope_dim
+    r, rd, rq = cfg.kv_lora_rank, cfg.qk_rope_dim, cfg.q_lora_rank
     ks = jax.random.split(key, 6)
     res_scale = 1.0 / math.sqrt(2 * cfg.num_layers)
-    return {
-        "wq": dense_init(ks[0], (d, H, hd + rd), d, dtype=dtype),
+    p = {
         "w_dkv": dense_init(ks[1], (d, r), d, dtype=dtype),
         "w_kr": dense_init(ks[2], (d, rd), d, dtype=dtype),
         "latent_norm": jnp.ones((r,), dtype),
@@ -86,6 +86,14 @@ def init_mla(key, cfg, dtype):
         "w_vb": dense_init(ks[4], (r, H, hd), r, dtype=dtype),
         "wo": dense_init(ks[5], (H, hd, d), H * hd, scale=res_scale, dtype=dtype),
     }
+    if rq:  # query LoRA: x @ w_qa, RMSNorm, @ w_qb
+        ka, kb = jax.random.split(ks[0])
+        p["w_qa"] = dense_init(ka, (d, rq), d, dtype=dtype)
+        p["q_norm"] = jnp.ones((rq,), dtype)
+        p["w_qb"] = dense_init(kb, (rq, H, hd + rd), rq, dtype=dtype)
+    else:
+        p["wq"] = dense_init(ks[0], (d, H, hd + rd), d, dtype=dtype)
+    return p
 
 
 def init_attention(key, cfg, dtype):
@@ -437,11 +445,67 @@ PAGED_ATTN_IMPL = "xla"
 # =====================================================================
 # MLA (DeepSeek-V2)
 # =====================================================================
+def _yarn_mscale(factor: float, mscale: float) -> float:
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def mla_inv_freq(cfg) -> np.ndarray:
+    """The rope key's inverse frequencies [qk_rope_dim / 2]: θ^(-2i/dim),
+    or under YaRN ``rope_scaling`` (DeepSeek-V2's modelling code) those
+    and the same divided by ``factor``, blended by a linear ramp between
+    the floor and the ceiling of the correction dims of ``beta_fast``
+    and ``beta_slow`` at ``original_max_position_embeddings``: the
+    fastest dims keep θ^(-2i/dim), the slowest are interpolated."""
+    dim, base = cfg.qk_rope_dim, cfg.rope_theta
+    extra = 1.0 / base ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
+    ys = dict(cfg.rope_scaling)
+    if not ys:
+        return extra.astype(np.float32)
+    orig = ys["original_max_position_embeddings"]
+
+    def corr(rot):
+        return (dim * math.log(orig / (rot * 2 * math.pi))
+                / (2 * math.log(base)))
+    lo = max(math.floor(corr(ys["beta_fast"])), 0)
+    hi = min(math.ceil(corr(ys["beta_slow"])), dim - 1)
+    if lo == hi:
+        hi += 0.001
+    ramp = np.clip((np.arange(dim // 2) - lo) / (hi - lo), 0.0, 1.0)
+    keep = 1.0 - ramp
+    inter = extra / ys["factor"]
+    return (inter * (1.0 - keep) + extra * keep).astype(np.float32)
+
+
+def mla_softmax_mscale(cfg) -> float:
+    """YaRN's factor on the softmax scale (hd + rd)^-0.5: mscale² of
+    ``mscale_all_dim`` (1.0 without ``rope_scaling``)."""
+    ys = dict(cfg.rope_scaling)
+    if not ys.get("mscale_all_dim"):
+        return 1.0
+    return _yarn_mscale(ys["factor"], ys["mscale_all_dim"]) ** 2
+
+
+def _mla_rope(cfg, positions):
+    """cos, sin [..., rd/2] of the rope key at ``positions``."""
+    ys = dict(cfg.rope_scaling)
+    if not ys:
+        return rope_cos_sin(positions, cfg.qk_rope_dim, cfg.rope_theta)
+    ang = positions[..., None].astype(jnp.float32) * mla_inv_freq(cfg)
+    m = (_yarn_mscale(ys["factor"], ys.get("mscale", 1.0))
+         / _yarn_mscale(ys["factor"], ys.get("mscale_all_dim", 1.0)))
+    return jnp.cos(ang) * m, jnp.sin(ang) * m
+
+
 def _mla_q(p, cfg, x, positions):
-    H, hd, rd = cfg.num_heads, cfg.head_dim, cfg.qk_rope_dim
-    q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
+    from repro.models.layers import rms_norm
+    hd = cfg.head_dim
+    if "w_qa" in p:
+        qa = rms_norm(x @ p["w_qa"], p["q_norm"], cfg.norm_eps)
+        q = jnp.einsum("bsr,rhk->bshk", qa, p["w_qb"])
+    else:
+        q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
     q_nope, q_rope = q[..., :hd], q[..., hd:]
-    cos, sin = rope_cos_sin(positions, rd, cfg.rope_theta)
+    cos, sin = _mla_rope(cfg, positions)
     q_rope = apply_rope(q_rope, cos[:, :, None, :], sin[:, :, None, :])
     return q_nope, q_rope
 
@@ -450,7 +514,7 @@ def _mla_latent(p, cfg, x, positions):
     from repro.models.layers import rms_norm
     latent = rms_norm(x @ p["w_dkv"], p["latent_norm"], cfg.norm_eps)
     k_rope = x @ p["w_kr"]  # [B,S,rd], shared across heads
-    cos, sin = rope_cos_sin(positions, cfg.qk_rope_dim, cfg.rope_theta)
+    cos, sin = _mla_rope(cfg, positions)
     k_rope = apply_rope(k_rope[:, :, None, :], cos[:, :, None, :],
                         sin[:, :, None, :])[:, :, 0, :]
     return latent, k_rope
@@ -469,9 +533,21 @@ def mla_full(p, cfg, x, positions, *, window: Optional[int] = None,
         [k_nope, jnp.broadcast_to(k_rope[:, :, None, :],
                                   (*k_rope.shape[:2], H, cfg.qk_rope_dim))],
         axis=-1)
-    # the default scale 1/sqrt(q.shape[-1]) IS 1/sqrt(hd + rd) here
+    # the default scale 1/sqrt(q.shape[-1]) IS 1/sqrt(hd + rd) here;
+    # YaRN's mscale² rides on q
+    m2 = mla_softmax_mscale(cfg)
+    if m2 != 1.0:
+        q = q * m2
     out = _sdpa(q, k, v, causal=causal, window=window)
     return jnp.einsum("bshk,hkd->bsd", out, p["wo"])
+
+
+def _mla_scale(s, cfg):
+    """Scores times the softmax scale (hd + rd)^-0.5, with YaRN's
+    mscale² where the config has it."""
+    s = s / math.sqrt(cfg.head_dim + cfg.qk_rope_dim)
+    m2 = mla_softmax_mscale(cfg)
+    return s * m2 if m2 != 1.0 else s
 
 
 def mla_cache_init(cfg, batch: int, cache_len: int, dtype):
@@ -514,7 +590,7 @@ def mla_decode(p, cfg, x, cache, pos, *, window: Optional[int] = None):
                    preferred_element_type=jnp.float32)
     s = s + jnp.einsum("bhk,blk->bhl", q_rope[:, 0].astype(cdt), k_rope,
                        preferred_element_type=jnp.float32)
-    s = s / math.sqrt(cfg.head_dim + cfg.qk_rope_dim)
+    s = _mla_scale(s, cfg)
 
     idx = jnp.arange(L)
     p_i = pos - jnp.mod(pos - idx, L)
@@ -554,7 +630,7 @@ def mla_decode_multipos(p, cfg, x, cache, pos_vec):
                    preferred_element_type=jnp.float32)
     s = s + jnp.einsum("bhk,blk->bhl", q_rope[:, 0].astype(cdt), k_rope,
                        preferred_element_type=jnp.float32)
-    s = s / math.sqrt(cfg.head_dim + cfg.qk_rope_dim)
+    s = _mla_scale(s, cfg)
 
     valid = jnp.arange(L)[None, :] <= positions  # [B, L]
     s = jnp.where(valid[:, None, :], s, NEG_INF)
@@ -584,43 +660,68 @@ def mla_decode_paged(p, cfg, x, cache, pos_vec, block_tables):
     ``gqa_decode_paged`` for the layout/exactness and multi-position
     append contracts — identical here, with the [T*bs] gathered strip
     standing in for the dense [L] latent cache)."""
-    B = x.shape[0]
-    bs = cache["latent"].shape[1]
-    positions = jnp.reshape(pos_vec, (B, 1)).astype(jnp.int32)
+    q_abs, q_rope, latent_new, k_rope_new = mla_decode_qkv(p, cfg, x, pos_vec)
+    cache = mla_append(cache, latent_new, k_rope_new,
+                       paged_cells(block_tables, pos_vec,
+                                   cache["latent"].shape[1]))
+    ctx = mla_attend_paged(q_abs, q_rope, cache, block_tables, pos_vec, cfg)
+    return mla_out(p["w_vb"], p["wo"], ctx, x.dtype), cache
+
+
+# The pieces of one paged MLA decode step (jitted piecewise by
+# ``repro.models.transformer``).
+def mla_decode_qkv(p, cfg, x, pos_vec):
+    """x [B,1,d], pos_vec [B] -> the query absorbed through ``w_kb``
+    q_abs [B,H,r] (fp32), the roped query q_rope [B,H,rd], and each
+    row's new latent [B,r] and rope key [B,rd]."""
+    positions = jnp.reshape(pos_vec, (x.shape[0], 1)).astype(jnp.int32)
     q_nope, q_rope = _mla_q(p, cfg, x, positions)
     latent_new, k_rope_new = _mla_latent(p, cfg, x, positions)
-
-    rows = jnp.arange(B)
-    blk = block_tables[rows, positions[:, 0] // bs]
-    off = positions[:, 0] % bs
-    latent = cache["latent"].at[blk, off].set(
-        latent_new[:, 0].astype(cache["latent"].dtype))
-    k_rope = cache["k_rope"].at[blk, off].set(
-        k_rope_new[:, 0].astype(cache["k_rope"].dtype))
-
-    T = block_tables.shape[1]
-    lg = latent[block_tables].reshape(B, T * bs, latent.shape[-1])
-    rg = k_rope[block_tables].reshape(B, T * bs, k_rope.shape[-1])
-
-    cdt = cache["latent"].dtype
+    # bf16 operands + fp32 accumulation; the latent pool is never
+    # up-cast (the f32 convert of the whole cache per layer was the
+    # baseline's dominant traffic)
     q_abs = jnp.einsum("bhk,rhk->bhr", q_nope[:, 0], p["w_kb"],
                        preferred_element_type=jnp.float32)
+    return q_abs, q_rope[:, 0], latent_new[:, 0], k_rope_new[:, 0]
+
+
+def mla_append(cache, latent_new, k_rope_new, cells):
+    """Scatter row b's latent [B,r] and rope key [B,rd] into ``cache``
+    at ``cells``."""
+    return {"latent": cache["latent"].at[cells].set(
+                latent_new.astype(cache["latent"].dtype)),
+            "k_rope": cache["k_rope"].at[cells].set(
+                k_rope_new.astype(cache["k_rope"].dtype))}
+
+
+def mla_attend_paged(q_abs, q_rope, cache, block_tables, pos_vec, cfg):
+    """The attention core in the latent space: q_abs [B,H,r] and q_rope
+    [B,H,rd] over the strip each row's table gathers from the pool,
+    keys at index <= pos_vec[b] -> the latent context [B,H,r] fp32."""
+    B, T = block_tables.shape
+    latent, k_rope = cache["latent"], cache["k_rope"]
+    bs = latent.shape[1]
+    lg = latent[block_tables].reshape(B, T * bs, latent.shape[-1])
+    rg = k_rope[block_tables].reshape(B, T * bs, k_rope.shape[-1])
+    cdt = latent.dtype
     s = jnp.einsum("bhr,blr->bhl", q_abs.astype(cdt), lg,
                    preferred_element_type=jnp.float32)
-    s = s + jnp.einsum("bhk,blk->bhl", q_rope[:, 0].astype(cdt), rg,
+    s = s + jnp.einsum("bhk,blk->bhl", q_rope.astype(cdt), rg,
                        preferred_element_type=jnp.float32)
-    s = s / math.sqrt(cfg.head_dim + cfg.qk_rope_dim)
-
-    valid = jnp.arange(T * bs)[None, :] <= positions  # [B, T*bs]
+    s = _mla_scale(s, cfg)
+    valid = jnp.arange(T * bs)[None, :] <= pos_vec.astype(jnp.int32)[:, None]
     s = jnp.where(valid[:, None, :], s, NEG_INF)
     w = jax.nn.softmax(s, axis=-1)
-    ctx = jnp.einsum("bhl,blr->bhr", w.astype(cdt), lg,
+    return jnp.einsum("bhl,blr->bhr", w.astype(cdt), lg,
+                      preferred_element_type=jnp.float32)
+
+
+def mla_out(w_vb, wo, ctx, dtype):
+    """The latent context [B,H,r] expanded through ``w_vb`` and ``wo``
+    -> [B,1,d]."""
+    out = jnp.einsum("bhr,rhk->bhk", ctx.astype(w_vb.dtype), w_vb,
                      preferred_element_type=jnp.float32)
-    out = jnp.einsum("bhr,rhk->bhk", ctx.astype(p["w_vb"].dtype), p["w_vb"],
-                     preferred_element_type=jnp.float32)
-    out = out[:, None].astype(x.dtype)  # [B,1,H,hd]
-    y = jnp.einsum("bshk,hkd->bsd", out, p["wo"])
-    return y, {"latent": latent, "k_rope": k_rope}
+    return jnp.einsum("bshk,hkd->bsd", out[:, None].astype(dtype), wo)
 
 
 # =====================================================================

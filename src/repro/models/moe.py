@@ -12,7 +12,9 @@ Three compute paths over one parameter layout:
 * the offload path lives in ``repro.core.offload_engine`` and reuses the
   same per-expert weights, streaming them through the expert cache.
 
-Routing: softmax top-k with renormalisation (Mixtral convention) plus
+Routing: one router (``route``) for every path, on the device or on
+the host: softmax top-k with renormalisation (Mixtral convention), or
+DeepSeek-V2's group-limited top-k scaled without renormalisation; plus
 the standard load-balance auxiliary loss (Shazeer 2017 / GShard).
 """
 from __future__ import annotations
@@ -22,6 +24,7 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.models.layers import dense_init
 from repro.models.sharding import active_mesh, active_rules, constrain
@@ -50,12 +53,57 @@ def init_moe(key, cfg, dtype):
     return p
 
 
+def _softmax(x, xp):
+    if xp is np:
+        e = np.exp(x - x.max(axis=-1, keepdims=True))
+        return e / e.sum(axis=-1, keepdims=True)
+    return jax.nn.softmax(x, axis=-1)
+
+
+def _top_k_ids(x, k: int, xp):
+    """Indices of the k largest entries along the last axis."""
+    if xp is np:
+        return np.argsort(-x, axis=-1)[..., :k]
+    return jax.lax.top_k(x, k)[1]
+
+
+def route(logits, cfg, xp=jnp):
+    """The router's choice from its fp32 logits [..., E]: (weights
+    [..., k], ids [..., k]). ``xp`` is ``jnp`` on the device or ``np``
+    on the host (``OffloadEngine._route``); both compute the same.
+
+    ``topk_method`` "greedy" with ``norm_topk_prob`` is the softmax over
+    the k largest logits (Mixtral; the renormalised top-k of the full
+    softmax). Otherwise the scores are the softmax over all E logits;
+    "group_limited_greedy" (DeepSeek-V2) splits the E experts into
+    ``n_group`` equal groups, keeps the ``topk_group`` groups whose best
+    score is highest and takes the top k inside them. The k scores are
+    then renormalised (``norm_topk_prob``) or multiplied by
+    ``routed_scaling_factor``."""
+    k = cfg.num_experts_per_tok
+    if cfg.topk_method == "greedy" and cfg.norm_topk_prob:
+        ids = _top_k_ids(logits, k, xp)
+        return _softmax(xp.take_along_axis(logits, ids, axis=-1), xp), ids
+    scores = _softmax(logits, xp)
+    pick = scores
+    if cfg.topk_method == "group_limited_greedy":
+        grouped = scores.reshape(*scores.shape[:-1], cfg.n_group, -1)
+        best = _top_k_ids(grouped.max(axis=-1), cfg.topk_group, xp)
+        keep = (xp.arange(cfg.n_group) == best[..., None]).any(axis=-2)
+        pick = xp.where(keep[..., None], grouped, 0.0).reshape(scores.shape)
+    elif cfg.topk_method != "greedy":
+        raise ValueError(f"unknown topk_method {cfg.topk_method!r}")
+    ids = _top_k_ids(pick, k, xp)
+    w = xp.take_along_axis(scores, ids, axis=-1)
+    if cfg.norm_topk_prob:
+        return w / (w.sum(axis=-1, keepdims=True) + 1e-20), ids
+    return w * cfg.routed_scaling_factor, ids
+
+
 def router_probs(p, cfg, x):
     """x [..., d] -> (gate_logits [..., E], topk probs [..., k], ids [..., k])."""
     logits = (x.astype(jnp.float32) @ p["router"]).astype(jnp.float32)
-    k = cfg.num_experts_per_tok
-    top_vals, top_ids = jax.lax.top_k(logits, k)
-    top_probs = jax.nn.softmax(top_vals, axis=-1)  # renormalised over top-k
+    top_probs, top_ids = route(logits, cfg)
     return logits, top_probs, top_ids
 
 
@@ -239,8 +287,7 @@ def moe_ep_shardmap(p, cfg, x, *, capacity_factor: Optional[float] = None
         Bl, Sl, dl = xl.shape
         xf = xl.reshape(Bl * Sl, dl)
         logits = (xf.astype(jnp.float32) @ router)
-        top_vals, top_ids = jax.lax.top_k(logits, k)
-        top_probs = jax.nn.softmax(top_vals, axis=-1)
+        top_probs, top_ids = route(logits, cfg)
 
         buf, slot, keep, fp = _dispatch_local(cfg, xf, top_probs, top_ids, C)
         # [E, C, d] -> exchange expert shards: [E/ep, C*ep, d]

@@ -109,7 +109,7 @@ def _spec_for(path: str, ndim: int, rules: dict) -> P:
         if name in ("bq", "bk", "bv"):
             return (m, None) if (name == "bq" or rules.get("shard_kv", True)) \
                 else (None, None)
-        if name in ("w_kb", "w_vb"):
+        if name in ("w_kb", "w_vb", "w_qb"):
             return (None, m, None)
         if name in ("w_dkv", "w_kr"):
             return (None, None)

@@ -4,7 +4,9 @@ every architecture family (dense, moe, ssm, hybrid, encdec, vlm).
 Layers are stacked into homogeneous groups and iterated with
 ``lax.scan`` so the lowered HLO stays small for 60-72 layer configs.
 
-  dense/moe/ssm : one stack [L]
+  dense/moe/ssm : one stack [L]; an MoE model with leading dense layers
+                  (first_k_dense_replace = k) has a dense stack [k]
+                  (``dense_layers``) ahead of the MoE stack [L-k]
   hybrid        : periods of ``attn_every``: attn stack [P] + ssm stack [P, per]
   vlm           : periods of ``cross_attn_every``: plain [P, per] + cross [P]
   encdec        : encoder stack [Le] + decoder-with-cross stack [Ld]
@@ -82,10 +84,19 @@ def init_params(cfg, key, dtype=None):
     if fam != "hybrid" and cfg.is_moe:
         assert cfg.moe_every == 1, "moe_every>1 only supported for hybrid"
     if fam in ("dense", "moe"):
+        # leading dense layers (DeepSeek-V2's first_k_dense_replace) are
+        # a stack of their own, ahead of the MoE stack
+        n_dense = cfg.first_k_dense_replace if cfg.is_moe else 0
         params["layers"] = _stack_init(
-            k_layers, cfg.num_layers,
+            k_layers, cfg.num_layers - n_dense,
             lambda k: _init_block(k, cfg, kind="attn", cross=False, causal=True,
                                   dtype=dtype))
+        if n_dense:
+            params["dense_layers"] = _stack_init(
+                jax.random.fold_in(k_layers, 1), n_dense,
+                lambda k: _init_block(k, cfg, kind="attn", cross=False,
+                                      causal=True, dtype=dtype,
+                                      use_moe=False))
     elif fam == "ssm":
         params["layers"] = _stack_init(
             k_layers, cfg.num_layers,
@@ -227,6 +238,8 @@ def forward(params, cfg, tokens, *, enc=None, window: Optional[int] = None,
     aux = jnp.zeros((), jnp.float32)
     if fam in ("dense", "moe", "ssm", "encdec"):
         kind = "ssm" if fam == "ssm" else "attn"
+        if "dense_layers" in params:
+            h, aux = scan_blocks(h, params["dense_layers"], kind, aux)
         h, aux = scan_blocks(h, params["layers"], kind, aux)
     elif fam == "hybrid":
         def period(carry, ps):
@@ -384,6 +397,32 @@ def _gqa_post(wo, h, out):
     return h + attn.gqa_out(wo, out, h.dtype)
 
 
+# The paged MLA block likewise: norm, the query (LoRA, rope, absorbed
+# through ``w_kb``), the new latent and rope key and their scatter
+# (``_mla_pre``); the attention core over the gathered latent strip
+# (``mla_attend_paged``, whose device time the benchmark reads under
+# that name); ``w_vb``, ``wo`` and the residual (``_mla_post``).
+@functools.partial(jax.jit, static_argnames=("cfg",))
+def _mla_pre(ln1, pa, cfg, h, cache, pos_vec, block_tables):
+    """-> (q_abs [B,H,r] in the pool's dtype, q_rope [B,H,rd], the pool
+    with each row's new latent and rope key written through the
+    table)."""
+    x = rms_norm(h, ln1, cfg.norm_eps)
+    q_abs, q_rope, latent, k_rope = attn.mla_decode_qkv(pa, cfg, x, pos_vec)
+    cells = attn.paged_cells(block_tables, pos_vec,
+                             cache["latent"].shape[1])
+    return (q_abs.astype(cache["latent"].dtype), q_rope,
+            attn.mla_append(cache, latent, k_rope, cells))
+
+
+_mla_attend_paged = jax.jit(attn.mla_attend_paged, static_argnames=("cfg",))
+
+
+@jax.jit
+def _mla_post(w_vb, wo, h, ctx):
+    return h + attn.mla_out(w_vb, wo, ctx, h.dtype)
+
+
 def _attn_decode_multipos(p, cfg, h, cache, pos_vec):
     """Per-row-position decode (continuous batching): pos_vec [B]."""
     if cfg.use_mla:
@@ -403,10 +442,11 @@ def _attn_decode_paged(p, cfg, h, cache, pos_vec, block_tables):
     virtual rows) — see the multi-position append contract on
     ``repro.models.attention.gqa_decode_paged``."""
     if cfg.use_mla:
-        x = rms_norm(h, p["ln1"], cfg.norm_eps)
-        y, cache = attn.mla_decode_paged(p["attn"], cfg, x, cache, pos_vec,
-                                         block_tables)
-        return h + y, cache
+        q_abs, q_rope, cache = _mla_pre(p["ln1"], p["attn"], cfg, h, cache,
+                                        pos_vec, block_tables)
+        ctx = _mla_attend_paged(q_abs, q_rope, cache, block_tables, pos_vec,
+                                cfg=cfg)
+        return _mla_post(p["attn"]["w_vb"], p["attn"]["wo"], h, ctx), cache
     q, cache = _gqa_pre(p["ln1"], p["attn"], cfg, h, cache, pos_vec,
                         block_tables)
     if attn.PAGED_ATTN_IMPL == "xla":
@@ -459,7 +499,17 @@ def decode_step(params, cfg, state, token, pos, *, window: Optional[int] = None,
             h, c = _block_decode(p, cfg, h, c, pos, kind=kind, window=window,
                                  cross_kv=ckv, moe_path=moe_path)
             return h, c
-        h, new_caches = jax.lax.scan(body, h, xs)
+        if "dense_layers" in params:
+            # the leading dense stack takes the first caches
+            k = jax.tree.leaves(params["dense_layers"])[0].shape[0]
+            first = jax.tree.map(lambda c: c[:k], state["layers"])
+            rest = jax.tree.map(lambda c: c[k:], state["layers"])
+            h, c0 = jax.lax.scan(body, h, (params["dense_layers"], first))
+            h, c1 = jax.lax.scan(body, h, (params["layers"], rest))
+            new_caches = jax.tree.map(
+                lambda a, b: jnp.concatenate([a, b]), c0, c1)
+        else:
+            h, new_caches = jax.lax.scan(body, h, xs)
         new_state["layers"] = new_caches
     elif fam == "hybrid":
         def body(h, xs_):

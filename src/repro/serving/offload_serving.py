@@ -249,7 +249,7 @@ class ContinuousOffloadServer:
                 host_bytes=host_budget_bytes, lanes=tier_lanes,
                 trace=self.trace)
             self.tiers.set_hbm_plan(
-                sum(c.device_nbytes() for c in self.engine.caches),
+                sum(c.device_nbytes() for c in self.engine.moe_caches),
                 self.engine.cost.kv_block_bytes(self.kv_block_size)
                 * self.paged.num_blocks)
             self.engine.attach_tiers(self.tiers)
@@ -336,7 +336,7 @@ class ContinuousOffloadServer:
             self.state = self.paged.state
             if self.tiers is not None:
                 self.tiers.set_hbm_plan(
-                    sum(c.device_nbytes() for c in self.engine.caches),
+                    sum(c.device_nbytes() for c in self.engine.moe_caches),
                     self.engine.cost.kv_block_bytes(self.kv_block_size)
                     * self.paged.num_blocks)
             return

@@ -43,8 +43,11 @@ def test_shared_experts_never_in_cache(dsv2_setup):
     cfg, params = dsv2_setup
     eng = OffloadEngine(params, cfg, cache_slots=3, policy="lru")
     eng.generate([1, 2, 3], 8)
-    # store holds exactly L x E routed experts
-    assert len(eng.store.keys()) == cfg.num_layers * cfg.num_experts
+    # store holds exactly E routed experts of each MoE layer (the first
+    # layer is dense)
+    moe_layers = sum(map(cfg.has_moe, range(cfg.num_layers)))
+    assert moe_layers == cfg.num_layers - 1
+    assert len(eng.store.keys()) == moe_layers * cfg.num_experts
 
 
 def test_offload_with_spec_prefetch_on_mla(dsv2_setup):

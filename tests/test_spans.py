@@ -38,7 +38,7 @@ ARGS = {
     "engine.decode": {"rows"},
     "engine.attention": {"layer"},
     "engine.moe": {"layer"},
-    "engine.route": {"layer"},
+    "engine.route": {"layer", "held", "routed"},
     "expert_cache.install": {"layer", "expert", "bytes", "demand"},
     "engine.ffn": {"layer", "experts", "rows"},
     "engine.logits": set(),
